@@ -1290,6 +1290,8 @@ def _only_filter(argv) -> list[str]:
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     fast = "--fast" in sys.argv
     json_path = _json_path(sys.argv)
     only = _only_filter(sys.argv)

@@ -14,39 +14,17 @@ Two layers:
   command-level ``BankSim`` loop — use it for quick sweeps; use the batched
   ``BankSim(trials=T)`` when command-level effects (pair selection, Frac
   staging, reference-side readout) matter.
-
-jax is a hard dependency of the repo (see pyproject), but this module still
-degrades gracefully: ``HAVE_JAX`` gates the jitted paths so pure-numpy
-consumers (``analog``/``calibrate``) never import it transitively.
 """
 from __future__ import annotations
 
-import numpy as np
+import functools
 
-try:  # pragma: no cover - exercised only on jax-less installs
-    import jax
-    import jax.numpy as jnp
-    HAVE_JAX = True
-except ImportError:  # pragma: no cover
-    jax = None
-    jnp = None
-    HAVE_JAX = False
+import jax
+import jax.numpy as jnp
+import numpy as np
 
 from . import analog as A
 from .analog import DEFAULT_PARAMS
-
-
-def _require_jax() -> None:
-    if not HAVE_JAX:
-        raise RuntimeError("repro.core.analog_jax requires jax; "
-                           "pip install -e .[test] provides it")
-
-
-def _maybe_jit(fn=None, **jit_kw):
-    """jax.jit when available, identity otherwise (keeps import working)."""
-    if fn is None:
-        return lambda f: _maybe_jit(f, **jit_kw)
-    return jax.jit(fn, **jit_kw) if HAVE_JAX else fn
 
 
 # ---------------------------------------------------------------------------
@@ -64,7 +42,7 @@ def mixture_cdf(x, s, b, w_plus, w_minus):
             + w_minus * phi((x - b) / s))
 
 
-@_maybe_jit
+@jax.jit
 def _success_table_kernel(m, dv, shift, s, b, wp, wm, pf, ideal):
     x = m + dv - shift
     p1 = mixture_cdf(x, s, b, wp, wm)
@@ -91,7 +69,6 @@ def _context(op: str, n: int, *, p=DEFAULT_PARAMS, temp_c=50.0,
 
 def boolean_success_table(op: str, n: int, **kw):
     """(n+1,) P(correct) per #logic-1 operands — jitted array math."""
-    _require_jax()
     p = kw.get("p", DEFAULT_PARAMS)
     s, b, wp, wm, dv, shift, pf = _context(op, n, **kw)
     k = np.arange(n + 1)
@@ -116,7 +93,7 @@ def not_success(n_dst: int, **kw) -> float:
 # ---------------------------------------------------------------------------
 # One-shot Monte-Carlo samplers
 # ---------------------------------------------------------------------------
-@_maybe_jit(static_argnames=("n", "trials", "width"))
+@functools.partial(jax.jit, static_argnames=("n", "trials", "width"))
 def _sample_boolean_kernel(key, table, n: int, trials: int, width: int):
     kb, ks = jax.random.split(key)
     bits = jax.random.bernoulli(kb, 0.5, (n, trials, width))
@@ -134,13 +111,12 @@ def sample_boolean_success(op: str, n: int, *, trials: int = 10_000,
     every (trial, column) against the success table, returns the mean —
     the software twin of the paper's 10k-trial protocol.
     """
-    _require_jax()
     table = boolean_success_table(op, n, **kw)
     key = jax.random.PRNGKey(seed)
     return float(_sample_boolean_kernel(key, table, n, trials, width))
 
 
-@_maybe_jit(static_argnames=("trials", "width"))
+@functools.partial(jax.jit, static_argnames=("trials", "width"))
 def _sample_not_kernel(key, p_ok, trials: int, width: int):
     ok = jax.random.uniform(key, (trials, width)) < p_ok
     return jnp.mean(ok)
@@ -149,7 +125,6 @@ def _sample_not_kernel(key, p_ok, trials: int, width: int):
 def sample_not_success(n_dst: int = 1, *, trials: int = 10_000,
                        width: int = 1024, seed: int = 0, **kw) -> float:
     """MC estimate of NOT success from the closed-form model, one call."""
-    _require_jax()
     p_ok = A.not_success(n_dst, **kw)
     key = jax.random.PRNGKey(seed)
     return float(_sample_not_kernel(key, p_ok, trials, width))

@@ -405,7 +405,7 @@ class FusedBankSim(BankSim):
             nz.astype(np.float32, copy=False), un,
             u_com=float(u_com), u_ref=float(u_ref), shift=0.0,
             pf=float(pf), trial_sigma=float(trial_sigma))
-        return np.asarray(out).astype(bool)
+        return np.asarray(out)
 
     # ---------------- fused APA ----------------
     def apa(self, rf_global, rl_global, *, first_act_restored: bool = False,

@@ -357,12 +357,9 @@ class BankSim:
     def _resolve_backend(self) -> str:
         """Effective resolve backend ('auto' settles on first use)."""
         if self.resolve_backend == "auto":
-            try:
-                import jax
-                self.resolve_backend = \
-                    "pallas" if jax.default_backend() == "tpu" else "numpy"
-            except Exception:          # jax not importable: numpy-only env
-                self.resolve_backend = "numpy"
+            import jax
+            self.resolve_backend = \
+                "pallas" if jax.default_backend() == "tpu" else "numpy"
         return self.resolve_backend
 
     def static_offsets(self, stripe: int, op: str, n: int, *,
@@ -604,7 +601,7 @@ class BankSim:
             u_com=float(u_com), u_ref=float(u_ref),
             shift=float(shift + p.delta_v - dv), pf=float(pf),
             trial_sigma=float(trial_sigma))
-        return np.asarray(out).astype(bool)
+        return np.asarray(out)
 
     def _maj_restore(self, sub: int, rows, cols: slice,
                      rng: np.random.Generator) -> None:

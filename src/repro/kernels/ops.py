@@ -1,9 +1,10 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute with ``interpret=True`` — the
-kernel body runs under the Pallas interpreter with identical semantics; on
-TPU the same calls compile to Mosaic.  ``repro.pud.engine`` and
-``repro.models.quant`` call through this module only.
+On CPU the kernels execute with ``interpret=True`` — the kernel body runs
+under the Pallas interpreter with identical semantics, which is how the
+tests run them; on TPU the same calls compile to Mosaic and never run
+interpreted.  ``repro.pud.engine`` and ``repro.models.quant`` call through
+this module only.
 """
 from __future__ import annotations
 
@@ -79,7 +80,7 @@ def senseamp_resolve_trials(com_cells, ref_cells, static, normals,
                             uniforms, *, u_com: float, u_ref: float,
                             shift: float, pf: float, trial_sigma: float,
                             interpret: bool | None = None) -> jax.Array:
-    """Trial-batched resolve: (T, N, W) cell slabs -> (T, W) uint8.
+    """Trial-batched resolve: (T, N, W) cell slabs -> (T, W) bool.
 
     The entry point ``BankSim(resolve_backend="pallas")`` calls per APA.
     """

@@ -140,7 +140,8 @@ def senseamp_resolve_trials(com_cells: jax.Array, ref_cells: jax.Array,
     """Trial-batched oracle of the fused charge-share + resolve kernel.
 
     com_cells/ref_cells: (T, N, W) cell voltages; static (W,) shared across
-    trials; normals (T, W); uniforms (2, T, W) -> (T, W) uint8.
+    trials, or (T, W) per trial; normals (T, W); uniforms (2, T, W)
+    -> (T, W) uint8.
     """
     v_com = jnp.sum(com_cells - 0.5, axis=1) * u_com       # (T, W)
     v_ref = jnp.sum(ref_cells - 0.5, axis=1) * u_ref

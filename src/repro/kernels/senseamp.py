@@ -13,7 +13,7 @@ axis into the lane axis), Mosaic-compiled on TPU and interpret-mode on
 CPU.  Matches ``repro.kernels.ref.senseamp_resolve`` and the numpy
 ``BankSim._resolve`` semantics.
 
-Inputs (W = number of shared columns, padded to a lane multiple):
+Inputs (W = number of shared columns):
   com_cells: (N_com, W) f32 — compute-side cell voltages in [0,1]
   ref_cells: (N_ref, W) f32 — reference-side voltages (constants + Frac)
   static:    (W,) f32       — per-SA static offsets [V]
@@ -21,7 +21,12 @@ Inputs (W = number of shared columns, padded to a lane multiple):
   uniforms:  (2, W) f32     — floor flip + coin draws
 Scalars (compile-time): u_com, u_ref (charge-share swing), shift, pf,
   trial_sigma.
-Output: (W,) uint8 resolved values.
+Output: (W,) bool resolved values.
+
+Layout: the column axis is padded to a whole number of (TILE_R, LANES)
+tiles and folded into 2-D ``(rows, LANES)`` planes, so every block is
+aligned to the VPU's (8, 128) tiling.  The kernel stores int32 (a dtype
+Mosaic writes); the cast to ``bool`` happens outside it.
 """
 from __future__ import annotations
 
@@ -31,26 +36,27 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-TILE_W = 1024
+TILE_R = 8
+LANES = 1024
 
 
 def _senseamp_kernel(com_ref, rf_ref, st_ref, nz_ref, un_ref, o_ref, *,
                      n_com: int, n_ref: int, u_com: float, u_ref: float,
                      shift: float, pf: float, trial_sigma: float):
-    v_com = jnp.zeros((TILE_W,), jnp.float32)
+    v_com = jnp.zeros((TILE_R, LANES), jnp.float32)
     for i in range(n_com):
         v_com = v_com + (com_ref[i] - 0.5)
     v_com = v_com * u_com
-    v_ref = jnp.zeros((TILE_W,), jnp.float32)
+    v_ref = jnp.zeros((TILE_R, LANES), jnp.float32)
     for i in range(n_ref):
         v_ref = v_ref + (rf_ref[i] - 0.5)
     v_ref = v_ref * u_ref
     margin = (v_com - v_ref - shift + st_ref[...]
               + trial_sigma * nz_ref[...])
-    out = margin > 0.0
-    flip = un_ref[0] < pf
-    coin = un_ref[1] < 0.5
-    o_ref[...] = jnp.where(flip, coin, out).astype(jnp.uint8)
+    one, zero = jnp.int32(1), jnp.int32(0)
+    out = jnp.where(margin > 0.0, one, zero)
+    coin = jnp.where(un_ref[1] < 0.5, one, zero)
+    o_ref[...] = jnp.where(un_ref[0] < pf, coin, out)
 
 
 @functools.partial(jax.jit,
@@ -63,33 +69,31 @@ def senseamp_resolve(com_cells: jax.Array, ref_cells: jax.Array,
                      interpret: bool = False) -> jax.Array:
     n_com, w = com_cells.shape
     n_ref = ref_cells.shape[0]
-    pw = (-w) % TILE_W
-    if pw:
-        pad1 = lambda x: jnp.pad(x, ((0, 0), (0, pw)))
-        out = senseamp_resolve(pad1(com_cells), pad1(ref_cells),
-                               jnp.pad(static, (0, pw)),
-                               jnp.pad(normals, (0, pw)),
-                               pad1(uniforms), u_com=u_com, u_ref=u_ref,
-                               shift=shift, pf=pf, trial_sigma=trial_sigma,
-                               interpret=interpret)
-        return out[:w]
-    grid = (w // TILE_W,)
-    return pl.pallas_call(
+    pw = (-w) % (TILE_R * LANES)
+    rows = (w + pw) // LANES
+
+    def fold(x):            # (..., W) -> (..., rows, LANES), zero-padded
+        x = jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pw),))
+        return x.reshape(*x.shape[:-1], rows, LANES)
+
+    out = pl.pallas_call(
         functools.partial(_senseamp_kernel, n_com=n_com, n_ref=n_ref,
                           u_com=u_com, u_ref=u_ref, shift=shift, pf=pf,
                           trial_sigma=trial_sigma),
-        out_shape=jax.ShapeDtypeStruct((w,), jnp.uint8),
-        grid=grid,
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
+        grid=(rows // TILE_R,),
         in_specs=[
-            pl.BlockSpec((n_com, TILE_W), lambda i: (0, i)),
-            pl.BlockSpec((n_ref, TILE_W), lambda i: (0, i)),
-            pl.BlockSpec((TILE_W,), lambda i: (i,)),
-            pl.BlockSpec((TILE_W,), lambda i: (i,)),
-            pl.BlockSpec((2, TILE_W), lambda i: (0, i)),
+            pl.BlockSpec((n_com, TILE_R, LANES), lambda i: (0, i, 0)),
+            pl.BlockSpec((n_ref, TILE_R, LANES), lambda i: (0, i, 0)),
+            pl.BlockSpec((TILE_R, LANES), lambda i: (i, 0)),
+            pl.BlockSpec((TILE_R, LANES), lambda i: (i, 0)),
+            pl.BlockSpec((2, TILE_R, LANES), lambda i: (0, i, 0)),
         ],
-        out_specs=pl.BlockSpec((TILE_W,), lambda i: (i,)),
+        out_specs=pl.BlockSpec((TILE_R, LANES), lambda i: (i, 0)),
         interpret=interpret,
-    )(com_cells, ref_cells, static, normals, uniforms)
+    )(fold(com_cells), fold(ref_cells), fold(static), fold(normals),
+      fold(uniforms))
+    return out.reshape(-1)[:w].astype(bool)
 
 
 @functools.partial(jax.jit,
@@ -111,7 +115,7 @@ def senseamp_resolve_trials(com_cells: jax.Array, ref_cells: jax.Array,
                offsets and margin shift folded into this plane)
     normals:   (T, W) f32         — per-trial standard normal draws
     uniforms:  (2, T, W) f32      — per-trial floor flip + coin draws
-    -> (T, W) uint8.  Every (trial, column) pair is an independent sense
+    -> (T, W) bool.  Every (trial, column) pair is an independent sense
     amp, so trials (and fused banks x trials) flatten losslessly into the
     kernel's lane axis (one pallas_call for the whole Monte-Carlo batch).
     """

@@ -38,8 +38,9 @@ def _mesh_for(remesh: str | None):
     if not remesh:
         return make_production_mesh(), "pod16x16"
     d, m = remesh.split("x")
-    return jax.make_mesh((int(d), int(m)), ("data", "model")), \
-        f"remap{remesh}"
+    mesh = jax.make_mesh((int(d), int(m)), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    return mesh, f"remap{remesh}"
 
 
 def run_variant(arch: str, shape_name: str, variant: str, *,

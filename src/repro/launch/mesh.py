@@ -12,12 +12,13 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(*, model: int | None = None):
@@ -25,7 +26,8 @@ def make_host_mesh(*, model: int | None = None):
     n = len(jax.devices())
     model = model or 1
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def dp_axes(mesh) -> tuple[str, ...]:

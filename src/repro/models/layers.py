@@ -29,11 +29,8 @@ def _dt(cfg: ModelConfig, kind: str):
 # activation-sharding constraints (GSPMD guidance; no-ops without a mesh)
 # ---------------------------------------------------------------------------
 def _mesh_axes() -> dict:
-    try:
-        m = jax.sharding.get_abstract_mesh()
-    except Exception:   # pragma: no cover
-        return {}
-    if m is None or not m.axis_names:
+    m = jax.sharding.get_abstract_mesh()
+    if not m.axis_names:
         return {}
     return {a: m.shape[a] for a in m.axis_names}
 

@@ -15,7 +15,7 @@ _SCRIPT = textwrap.dedent("""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
     from repro.models.config import ModelConfig, TrainConfig
     from repro.train import step as TS
@@ -23,7 +23,8 @@ _SCRIPT = textwrap.dedent("""
                                        to_shardings)
 
     assert len(jax.devices()) == 8
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     cfg = ModelConfig("t", 2, 64, 4, 2, 128, 256, head_dim=16)
     tc = TrainConfig(learning_rate=1e-3, n_microbatches=2)
 
@@ -74,6 +75,7 @@ _SCRIPT = textwrap.dedent("""
 def test_sharded_train_step_matches_single_device(tmp_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
                          capture_output=True, text=True, timeout=500,
                          cwd=os.path.dirname(os.path.dirname(__file__)))

@@ -71,7 +71,8 @@ def test_bitcount_planes(n):
 
 @pytest.mark.parametrize("kind", ["and", "xnor"])
 @pytest.mark.parametrize("m,n,kb", [(8, 8, 2), (100, 70, 40), (128, 128, 64),
-                                    (130, 50, 65)])
+                                    (130, 50, 65), (9, 5, 1), (9, 5, 63),
+                                    (9, 5, 130)])
 def test_popcount_gemm(kind, m, n, kb):
     x = jnp.asarray(RNG.integers(0, 2 ** 32, (m, kb), dtype=np.uint32))
     w = jnp.asarray(RNG.integers(0, 2 ** 32, (n, kb), dtype=np.uint32))
@@ -129,6 +130,38 @@ def test_senseamp_resolve_trials_matches_ref():
     got = ops.senseamp_resolve_trials(com, rfc, st_, nz, un, **kw)
     want = ref.senseamp_resolve_trials(com, rfc, st_, nz, un, **kw)
     assert got.shape == (t, w)
+    assert (np.asarray(got) == np.asarray(want)).all()
+
+
+@pytest.mark.parametrize("w", [1, 1000, 8191, 8193])
+def test_senseamp_padded_widths(w):
+    """Widths that are not a whole number of (8, 1024) tiles pad and fold
+    back to exactly the reference decisions."""
+    com = jnp.asarray(RNG.random((3, w), dtype=np.float32))
+    rfc = jnp.asarray(RNG.random((2, w), dtype=np.float32))
+    st_ = jnp.asarray(RNG.normal(0, .02, w).astype(np.float32))
+    nz = jnp.asarray(RNG.normal(0, 1, w).astype(np.float32))
+    un = jnp.asarray(RNG.random((2, w), dtype=np.float32))
+    kw = dict(shift=.01, pf=.04, trial_sigma=.012)
+    got = ops.senseamp_resolve(com, rfc, st_, nz, un, u_com=.1, u_ref=.08,
+                               **kw)
+    want = ref.senseamp_resolve(
+        (com - 0.5).sum(0) * .1, (rfc - 0.5).sum(0) * .08, st_, nz, un, **kw)
+    assert got.shape == (w,) and got.dtype == jnp.bool_
+    assert (np.asarray(got) == np.asarray(want)).all()
+
+
+def test_senseamp_resolve_trials_per_trial_static():
+    """The fused path's (T, W) static plane (one chip per stacked bank)."""
+    t, n, w = 3, 2, 900
+    com = jnp.asarray(RNG.random((t, n, w), dtype=np.float32))
+    rfc = jnp.asarray(RNG.random((t, n, w), dtype=np.float32))
+    st_ = jnp.asarray(RNG.normal(0, .02, (t, w)).astype(np.float32))
+    nz = jnp.asarray(RNG.normal(0, 1, (t, w)).astype(np.float32))
+    un = jnp.asarray(RNG.random((2, t, w), dtype=np.float32))
+    kw = dict(u_com=.09, u_ref=.11, shift=.015, pf=.03, trial_sigma=.01)
+    got = ops.senseamp_resolve_trials(com, rfc, st_, nz, un, **kw)
+    want = ref.senseamp_resolve_trials(com, rfc, st_, nz, un, **kw)
     assert (np.asarray(got) == np.asarray(want)).all()
 
 
