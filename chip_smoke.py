@@ -33,6 +33,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 
 import numpy as np
 
+from repro import tracing
 from repro.compile_cache import enable_compile_cache
 from repro.core import calibrate, charz
 from repro.core import analog as A
@@ -53,25 +54,17 @@ RESOLVE_MISMATCH_TOL = 1e-3
 #: MC-vs-closed-form bound for the characterization phase [points]
 CLOSED_FORM_TOL = 3.0
 
-#: calls of the trial-batched sense-amp kernel entry point
-_KERNEL_CALLS = [0]
-
 
 def _check(ok: bool, what: str) -> None:
     if not ok:
         raise RuntimeError(f"smoke check failed: {what}")
 
 
-def _count_kernel_calls() -> None:
-    """Count every resolve that reaches the sense-amp kernel (the
-    simulator looks the entry point up on ``kops`` at each call)."""
-    inner = kops.senseamp_resolve_trials
-
-    def counted(*args, **kwargs):
-        _KERNEL_CALLS[0] += 1
-        return inner(*args, **kwargs)
-
-    kops.senseamp_resolve_trials = counted
+def _kernel_calls() -> int:
+    """Resolves that reached the sense-amp kernel so far (the calls of the
+    program's ``sim.resolve_call`` span; ``main`` turns tracing on)."""
+    row = tracing.snapshot()["spans"].get("sim.resolve_call")
+    return row["calls"] if row else 0
 
 
 def _bits(words) -> np.ndarray:
@@ -87,7 +80,7 @@ def _pack(bits) -> np.ndarray:
 
 def phase_resolve_parity(trials: int = 64) -> dict:
     worst = 0.0
-    calls0 = _KERNEL_CALLS[0]
+    calls0 = _kernel_calls()
     for op in ("and", "or", "nand", "nor"):
         for n in (2, 4, 8, 16):
             outs = {}
@@ -105,20 +98,20 @@ def phase_resolve_parity(trials: int = 64) -> dict:
             _check(frac <= RESOLVE_MISMATCH_TOL,
                    f"{op}{n} resolve mismatch {frac} > {RESOLVE_MISMATCH_TOL}")
             worst = max(worst, frac)
-    _check(_KERNEL_CALLS[0] > calls0, "no resolve reached the Pallas kernel")
+    _check(_kernel_calls() > calls0, "no resolve reached the Pallas kernel")
     return {"worst_mismatch": worst, "tol": RESOLVE_MISMATCH_TOL}
 
 
 def phase_charz(trials: int = 216) -> dict:
     out = {}
-    calls0 = _KERNEL_CALLS[0]
+    calls0 = _kernel_calls()
     ctx = {"die_rev": "M", "density_gb": 4}     # the MC's default module
     for op in ("nand", "nor"):
         got = 100.0 * charz.mc_boolean_success(op, 16, trials=trials,
                                                row_bits=ROW_BITS)
         want = calibrate._avg(op, 16, A.DEFAULT_PARAMS, **ctx)
         out[f"{op}16"] = (got, want)
-    _check(_KERNEL_CALLS[0] > calls0,
+    _check(_kernel_calls() > calls0,
            "charz Boolean MC did not resolve through the Pallas kernel")
     got = 100.0 * charz.mc_not_success(trials=trials, row_bits=ROW_BITS)
     out["not1"] = (got, calibrate._not(1, A.DEFAULT_PARAMS, **ctx))
@@ -236,7 +229,7 @@ def main() -> int:
             cache["requests"] += 1
 
     jax.monitoring.register_event_listener(on_event)
-    _count_kernel_calls()
+    tracing.enable()
     print(f"device {dev.platform} {dev.device_kind} x{len(devices)}; "
           f"compile cache {cache_dir}")
     summary = {}
@@ -248,7 +241,7 @@ def main() -> int:
         print(f"phase {name}: ok, wall {wall!r} s (smoke timing)")
         summary[name] = {"wall_s": wall, **result}
     summary["senseamp"] = {
-        "kernel_calls": _KERNEL_CALLS[0],
+        "kernel_calls": _kernel_calls(),
         "compiles": senseamp.senseamp_resolve_trials._cache_size()}
     summary["compile_cache"] = {"dir": cache_dir, **cache}
     print(json.dumps({"smoke": summary}))

@@ -35,6 +35,7 @@ from functools import lru_cache
 
 import numpy as np
 
+from .. import tracing
 from . import analog as A
 from . import compiler as CC
 from . import decoder as DEC
@@ -200,7 +201,8 @@ def _bank_pair_schedule(arr: BankArray, groups: int, pairs_of, *,
     its = {}
     for b in _deal_groups(arr, groups, dealer, weights):
         if b not in its:
-            its[b] = iter(pairs_of(arr.isa(b)))
+            with tracing.span("charz.chip"):
+                its[b] = iter(pairs_of(arr.isa(b)))
         pair = next(its[b], None)
         if pair is not None:        # a bank may drop decoder-miss groups
             yield arr.isa(b), pair
@@ -218,13 +220,15 @@ def _fused_mc_rounds(arr: BankArray, groups: int, run_round) -> None:
     ``run_round(fisa, r)`` performs round r's draws, ops and accounting.
     """
     full, tail = divmod(groups, arr.banks)
-    fisa = arr.fused_isa() if full else None
+    with tracing.span("charz.chip"):
+        fisa = arr.fused_isa() if full else None
     for r in range(full):
         run_round(fisa, r)
     if tail:
-        ft = arr.fused_isa(n_banks=tail)
-        if fisa is not None:
-            ft.adopt_state(fisa)
+        with tracing.span("charz.chip"):
+            ft = arr.fused_isa(n_banks=tail)
+            if fisa is not None:
+                ft.adopt_state(fisa)
         run_round(ft, full)
         if fisa is not None:
             # fold the tail's cursor/counter advances back so the next
@@ -309,59 +313,74 @@ def mc_boolean_success(op: str, n: int, *, trials: int = 200,
     (per-bank time, makespan).
     """
     banks = _check_banks(banks, batched=batched)
-    if not batched:
-        sim = BankSim(module or get_module(), row_bits=row_bits, seed=seed,
-                      temp_c=temp_c, error_model="analog")
-        isa = PudIsa(sim)
+    with tracing.span("charz.estimate", op=op, n=n, seed=seed, banks=banks):
+        if not batched:
+            tracing.count("charz.trials", trials)
+            with tracing.span("charz.chip"):
+                sim = BankSim(module or get_module(), row_bits=row_bits,
+                              seed=seed, temp_c=temp_c, error_model="analog")
+                isa = PudIsa(sim)
+            rng = np.random.default_rng(seed + 1)
+            ok = 0
+            tot = 0
+            for _t in range(trials):
+                ops = [rng.integers(0, 2, isa.width).astype(np.uint8)
+                       for _ in range(n)]
+                got = isa.nary_op(op, ops)
+                ok += int(np.sum(got == _want_nary(op, ops)))
+                tot += isa.width
+            return ok / tot
+        tg = max(1, -(-trials // groups))
+        with tracing.span("charz.chip"):
+            arr = BankArray(module or get_module(), banks=banks,
+                            row_bits=row_bits, seed=seed, temp_c=temp_c,
+                            error_model="analog", trials=tg,
+                            track_unshared=False)
         rng = np.random.default_rng(seed + 1)
         ok = 0
         tot = 0
-        for _t in range(trials):
-            ops = [rng.integers(0, 2, isa.width).astype(np.uint8)
-                   for _ in range(n)]
-            got = isa.nary_op(op, ops)
-            ok += int(np.sum(got == _want_nary(op, ops)))
-            tot += isa.width
-        return ok / tot
-    tg = max(1, -(-trials // groups))
-    arr = BankArray(module or get_module(), banks=banks, row_bits=row_bits,
-                    seed=seed, temp_c=temp_c, error_model="analog",
-                    trials=tg, track_unshared=False)
-    rng = np.random.default_rng(seed + 1)
-    ok = 0
-    tot = 0
-    if _use_fused(fused, arr.module, banks, dealer):
-        pairs_by_bank = [_stratified_pairs(arr.isa(b), n, n, groups,
-                                           seed=seed)
-                         for b in range(min(banks, groups))]
+        if _use_fused(fused, arr.module, banks, dealer):
+            with tracing.span("charz.chip"):
+                pairs_by_bank = [_stratified_pairs(arr.isa(b), n, n, groups,
+                                                   seed=seed)
+                                 for b in range(min(banks, groups))]
 
-        def run_round(fisa, r):
-            nonlocal ok, tot
-            k = fisa.n_banks
-            # draw per group in global round-robin order, stack bank-major
-            ops = np.concatenate([_random_bits(rng, (tg, n, fisa.width))
-                                  for _b in range(k)])
-            pairs = [pairs_by_bank[b][r] for b in range(k)]
-            got = fisa.nary_op(op, ops.swapaxes(0, 1), pair=pairs)
-            ok += int(np.sum(got == _want_nary(op, ops, axis=1)))
-            tot += got.size
+            def run_round(fisa, r):
+                nonlocal ok, tot
+                k = fisa.n_banks
+                # draw per group in global round-robin order, stack
+                # bank-major
+                with tracing.span("charz.draw"):
+                    ops = np.concatenate(
+                        [_random_bits(rng, (tg, n, fisa.width))
+                         for _b in range(k)])
+                pairs = [pairs_by_bank[b][r] for b in range(k)]
+                with tracing.span("charz.op"):
+                    got = fisa.nary_op(op, ops.swapaxes(0, 1), pair=pairs)
+                with tracing.span("charz.count"):
+                    ok += int(np.sum(got == _want_nary(op, ops, axis=1)))
+                    tot += got.size
+                tracing.count("charz.trials", got.shape[0])
 
-        _fused_mc_rounds(arr, groups, run_round)
+            _fused_mc_rounds(arr, groups, run_round)
+            _fill_stats(stats, arr, groups, tg)
+            return ok / tot
+        for isa, pair in _bank_pair_schedule(
+                arr, groups, lambda isa: _stratified_pairs(isa, n, n, groups,
+                                                           seed=seed),
+                dealer=dealer):
+            isa.sim.recycle_rows()      # bound the hot working set to one op
+            # trial-major draw: operand staging reads it contiguously
+            with tracing.span("charz.draw"):
+                ops = _random_bits(rng, (tg, n, isa.width))
+            with tracing.span("charz.op"):
+                got = isa.nary_op(op, ops.swapaxes(0, 1), pair=pair)
+            with tracing.span("charz.count"):
+                ok += int(np.sum(got == _want_nary(op, ops, axis=1)))
+                tot += got.size
+            tracing.count("charz.trials", got.shape[0])
         _fill_stats(stats, arr, groups, tg)
         return ok / tot
-    for isa, pair in _bank_pair_schedule(
-            arr, groups, lambda isa: _stratified_pairs(isa, n, n, groups,
-                                                       seed=seed),
-            dealer=dealer):
-        isa.sim.recycle_rows()      # bound the hot working set to one op
-        # trial-major draw: operand staging reads it contiguously
-        ops = _random_bits(rng, (tg, n, isa.width))
-        got = isa.nary_op(op, ops.swapaxes(0, 1), pair=pair)
-        ok += int(np.sum(got == _want_nary(op, ops, axis=1)))
-        tot += got.size
-    _fill_stats(stats, arr, groups, tg)
-    return ok / tot
-
 
 def mc_not_success(n_dst: int = 1, *, trials: int = 200, row_bits: int = 2048,
                    seed: int = 0, module: str | None = None,
@@ -372,58 +391,73 @@ def mc_not_success(n_dst: int = 1, *, trials: int = 200, row_bits: int = 2048,
                    stats: dict | None = None) -> float:
     """NOT-protocol MC success; knobs as :func:`mc_boolean_success`."""
     banks = _check_banks(banks, batched=batched)
-    if not batched:
-        sim = BankSim(module or get_module(), row_bits=row_bits, seed=seed,
-                      error_model="analog")
-        isa = PudIsa(sim)
+    with tracing.span("charz.estimate", op="not", n=n_dst, seed=seed,
+                      banks=banks):
+        if not batched:
+            tracing.count("charz.trials", trials)
+            with tracing.span("charz.chip"):
+                sim = BankSim(module or get_module(), row_bits=row_bits,
+                              seed=seed, error_model="analog")
+                isa = PudIsa(sim)
+            rng = np.random.default_rng(seed + 1)
+            ok = 0
+            tot = 0
+            for _t in range(trials):
+                bits = rng.integers(0, 2, isa.width).astype(np.uint8)
+                got = isa.op_not(bits, n_dst=n_dst)
+                ok += int(np.sum(got == 1 - bits))
+                tot += isa.width
+            return ok / tot
+        tg = max(1, -(-trials // groups))
+        with tracing.span("charz.chip"):
+            arr = BankArray(module or get_module(), banks=banks,
+                            row_bits=row_bits, seed=seed,
+                            error_model="analog", trials=tg,
+                            track_unshared=False)
         rng = np.random.default_rng(seed + 1)
         ok = 0
         tot = 0
-        for _t in range(trials):
-            bits = rng.integers(0, 2, isa.width).astype(np.uint8)
-            got = isa.op_not(bits, n_dst=n_dst)
-            ok += int(np.sum(got == 1 - bits))
-            tot += isa.width
-        return ok / tot
-    tg = max(1, -(-trials // groups))
-    arr = BankArray(module or get_module(), banks=banks, row_bits=row_bits,
-                    seed=seed, error_model="analog", trials=tg,
-                    track_unshared=False)
-    rng = np.random.default_rng(seed + 1)
-    ok = 0
-    tot = 0
-    if _use_fused(fused, arr.module, banks, dealer):
-        pairs_by_bank = [
-            _stratified_pairs(arr.isa(b), arr.isa(b).not_activation(n_dst),
-                              n_dst, groups, seed=seed)
-            for b in range(min(banks, groups))]
+        if _use_fused(fused, arr.module, banks, dealer):
+            with tracing.span("charz.chip"):
+                pairs_by_bank = [
+                    _stratified_pairs(arr.isa(b),
+                                      arr.isa(b).not_activation(n_dst),
+                                      n_dst, groups, seed=seed)
+                    for b in range(min(banks, groups))]
 
-        def run_round(fisa, r):
-            nonlocal ok, tot
-            k = fisa.n_banks
-            bits = np.concatenate([_random_bits(rng, (tg, fisa.width))
-                                   for _b in range(k)])
-            pairs = [pairs_by_bank[b][r] for b in range(k)]
-            got = fisa.op_not(bits, n_dst=n_dst, pair=pairs)
-            ok += int(np.sum(got == 1 - bits))
-            tot += got.size
+            def run_round(fisa, r):
+                nonlocal ok, tot
+                k = fisa.n_banks
+                with tracing.span("charz.draw"):
+                    bits = np.concatenate([_random_bits(rng, (tg, fisa.width))
+                                           for _b in range(k)])
+                pairs = [pairs_by_bank[b][r] for b in range(k)]
+                with tracing.span("charz.op"):
+                    got = fisa.op_not(bits, n_dst=n_dst, pair=pairs)
+                with tracing.span("charz.count"):
+                    ok += int(np.sum(got == 1 - bits))
+                    tot += got.size
+                tracing.count("charz.trials", got.shape[0])
 
-        _fused_mc_rounds(arr, groups, run_round)
+            _fused_mc_rounds(arr, groups, run_round)
+            _fill_stats(stats, arr, groups, tg)
+            return ok / tot
+        for isa, pair in _bank_pair_schedule(
+                arr, groups,
+                lambda isa: _stratified_pairs(isa, isa.not_activation(n_dst),
+                                              n_dst, groups, seed=seed),
+                dealer=dealer):
+            isa.sim.recycle_rows()      # bound the hot working set to one op
+            with tracing.span("charz.draw"):
+                bits = _random_bits(rng, (tg, isa.width))
+            with tracing.span("charz.op"):
+                got = isa.op_not(bits, n_dst=n_dst, pair=pair)
+            with tracing.span("charz.count"):
+                ok += int(np.sum(got == 1 - bits))
+                tot += got.size
+            tracing.count("charz.trials", got.shape[0])
         _fill_stats(stats, arr, groups, tg)
         return ok / tot
-    for isa, pair in _bank_pair_schedule(
-            arr, groups,
-            lambda isa: _stratified_pairs(isa, isa.not_activation(n_dst),
-                                          n_dst, groups, seed=seed),
-            dealer=dealer):
-        isa.sim.recycle_rows()      # bound the hot working set to one op
-        bits = _random_bits(rng, (tg, isa.width))
-        got = isa.op_not(bits, n_dst=n_dst, pair=pair)
-        ok += int(np.sum(got == 1 - bits))
-        tot += got.size
-    _fill_stats(stats, arr, groups, tg)
-    return ok / tot
-
 
 def measure_cell_map(op: str, n: int, *, trials: int = 300,
                      row_bits: int = 2048, seed: int = 0,

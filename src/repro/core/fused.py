@@ -68,13 +68,14 @@ import math
 
 import numpy as np
 
+from .. import tracing
 from . import analog as A
 from . import decoder as DEC
 from .analog import ALL_OPS, _base_op
 from .device import ActivationSupport, ENERGY_PJ, VIOLATED_TRAS_NS, \
     VIOLATED_TRP_NS
 from .isa import CapabilityError, PudIsa, inventory_for
-from .simulator import STATIC_SPLIT, BankSim, _norm_ppf
+from .simulator import STATIC_SPLIT, BankSim, _norm_ppf, _resolve_call
 
 
 class FusedExecutionError(RuntimeError):
@@ -383,31 +384,31 @@ class FusedBankSim(BankSim):
     def _resolve_pallas(self, com_cells, ref_cells, u_com, u_ref,
                         stripe: int, op: str, n: int, *, regions,
                         random_pattern: bool, rng) -> np.ndarray:
-        from ..kernels import ops as kops
         p = self.params
-        dv, s, shift, static, pf = self._resolve_params(
-            stripe, op, n, regions=regions, random_pattern=random_pattern)
-        shape = com_cells.shape[:1] + com_cells.shape[2:]      # (N*T, w)
-        nz = rng.standard_normal(shape, dtype=self._noise_dtype)
-        u = rng.random(shape, dtype=self._noise_dtype)
-        coin = np.where(u < 0.5 * pf, np.float32(0.0), np.float32(1.0))
-        un = np.stack([u.astype(np.float32, copy=False), coin])
-        trial_sigma = math.sqrt(max(1.0 - STATIC_SPLIT ** 2, 0.0)) * s
-        # per-bank threshold shift folded into the per-trial static plane
-        # (kernel margin: v_com - v_ref - shift + static + noise)
-        shift_col = np.repeat(
-            np.asarray([shift + p.delta_v - dv_b for dv_b in dv],
-                       dtype=np.float32), self.trials_per_bank)
-        static_eff = static.astype(np.float32, copy=False) \
-            - shift_col[:, None]
-        out = kops.senseamp_resolve_trials(
-            com_cells, ref_cells, static_eff,
-            nz.astype(np.float32, copy=False), un,
+        with tracing.span("sim.resolve_prep"):
+            dv, s, shift, static, pf = self._resolve_params(
+                stripe, op, n, regions=regions, random_pattern=random_pattern)
+            shape = com_cells.shape[:1] + com_cells.shape[2:]      # (N*T, w)
+            nz = rng.standard_normal(shape, dtype=self._noise_dtype)
+            u = rng.random(shape, dtype=self._noise_dtype)
+            coin = np.where(u < 0.5 * pf, np.float32(0.0), np.float32(1.0))
+            un = np.stack([u.astype(np.float32, copy=False), coin])
+            trial_sigma = math.sqrt(max(1.0 - STATIC_SPLIT ** 2, 0.0)) * s
+            # per-bank threshold shift folded into the per-trial static plane
+            # (kernel margin: v_com - v_ref - shift + static + noise)
+            shift_col = np.repeat(
+                np.asarray([shift + p.delta_v - dv_b for dv_b in dv],
+                           dtype=np.float32), self.trials_per_bank)
+            static_eff = static.astype(np.float32, copy=False) \
+                - shift_col[:, None]
+            nz = nz.astype(np.float32, copy=False)
+        return _resolve_call(
+            com_cells, ref_cells, static_eff, nz, un,
             u_com=float(u_com), u_ref=float(u_ref), shift=0.0,
             pf=float(pf), trial_sigma=float(trial_sigma))
-        return np.asarray(out)
 
     # ---------------- fused APA ----------------
+    @tracing.traced("sim.apa")
     def apa(self, rf_global, rl_global, *, first_act_restored: bool = False,
             random_pattern: bool = True) -> "FusedActivation":
         rps = self.geom.rows_per_subarray
@@ -686,10 +687,11 @@ class FusedPudIsa(PudIsa):
             raise NotImplementedError(
                 "fused execution stages operands from the host "
                 "(resident row chaining is loop-path only)")
-        self.sim.recycle_rows()     # lockstep slot allocation (module doc)
-        self.sim.write_cols_multi(
-            self.f_sub, PerBank(act.rows_f), self._f_sl,
-            np.asarray(payload, dtype=np.float32)[..., None, :])
+        with tracing.span("isa.stage"):
+            self.sim.recycle_rows()     # lockstep slot allocation (module doc)
+            self.sim.write_cols_multi(
+                self.f_sub, PerBank(act.rows_f), self._f_sl,
+                np.asarray(payload, dtype=np.float32)[..., None, :])
         self.stats.writes += act.n_rf
         self.stats.cost = self.stats.cost \
             + self.cost_model.write_row().scaled(act.n_rf)
@@ -752,22 +754,23 @@ class FusedPudIsa(PudIsa):
         if not (isinstance(sources, tuple) and sources[0] == "write_stack"):
             raise NotImplementedError(
                 "fused execution stages operands with ('write_stack', ops)")
-        self.sim.recycle_rows()     # lockstep slot allocation (module doc)
         n = act.n_rf
         base, _is_ref = _base_op(op.lower())
         const = 1.0 if base == "and" else 0.0
-        self.sim.fill_rows(self.f_sub, PerBank(act.rows_f[:, :-1]), const,
-                           cols=self._f_sl)
-        self.stats.writes += n - 1
-        self.stats.cost = self.stats.cost \
-            + self.cost_model.write_row().scaled(n - 1)
-        self.sim.frac_row(self.f_sub, PerBank(act.rows_f[:, -1]))
-        self.stats.fracs += 1
-        stack = self._stack_words(sources[1])
-        n_wr = stack.shape[-2]
-        self.sim.write_cols_multi(self.l_sub, PerBank(act.rows_l[:, :n_wr]),
-                                  self._l_sl, stack)
-        self.stats.writes += n_wr
+        with tracing.span("isa.stage"):
+            self.sim.recycle_rows()     # lockstep slot allocation (module doc)
+            self.sim.fill_rows(self.f_sub, PerBank(act.rows_f[:, :-1]), const,
+                               cols=self._f_sl)
+            self.stats.writes += n - 1
+            self.stats.cost = self.stats.cost \
+                + self.cost_model.write_row().scaled(n - 1)
+            self.sim.frac_row(self.f_sub, PerBank(act.rows_f[:, -1]))
+            self.stats.fracs += 1
+            stack = self._stack_words(sources[1])
+            n_wr = stack.shape[-2]
+            self.sim.write_cols_multi(self.l_sub, PerBank(act.rows_l[:, :n_wr]),
+                                      self._l_sl, stack)
+            self.stats.writes += n_wr
         self.sim.op_boolean(op, self.sim.global_addr(self.f_sub, rf),
                             self.sim.global_addr(self.l_sub, rl),
                             random_pattern=random_pattern)

@@ -25,6 +25,7 @@ from functools import lru_cache
 
 import numpy as np
 
+from .. import tracing
 from . import decoder as DEC
 from .analog import ALL_OPS, _base_op
 from .device import (ENERGY_PJ, ModuleConfig, get_module, timings_for,
@@ -96,7 +97,8 @@ class CapabilityError(RuntimeError):
 
 @lru_cache(maxsize=16)
 def _inventory(module_name: str, seed: int) -> PairInventory:
-    return PairInventory(get_module(module_name), seed=seed)
+    with tracing.span("isa.inventory"):
+        return PairInventory(get_module(module_name), seed=seed)
 
 
 def inventory_for(module: ModuleConfig, seed: int = 0) -> PairInventory:
@@ -386,7 +388,8 @@ class PudIsa:
         sl = self._f_sl if side == "f" else self._l_sl
         self.stats.reads += 1
         self.stats.cost = self.stats.cost + self.cost_model.read_row()
-        return self.sim.read_shared_word(sub, row, sl)
+        with tracing.span("isa.readout"):
+            return self.sim.read_shared_word(sub, row, sl)
 
     def read_result_word(self, sub: int, row: int) -> np.ndarray:
         """Public result readout for row handles (resident executor)."""
@@ -488,9 +491,10 @@ class PudIsa:
             for r in act.rows_f:
                 self.clone_word(self.f_sub, int(payload), int(r))
         else:
-            self.sim.write_cols_multi(
-                self.f_sub, act.rows_f, self._f_sl,
-                np.asarray(payload, dtype=np.float32)[..., None, :])
+            with tracing.span("isa.stage"):
+                self.sim.write_cols_multi(
+                    self.f_sub, act.rows_f, self._f_sl,
+                    np.asarray(payload, dtype=np.float32)[..., None, :])
             self.stats.writes += act.n_rf
             self.stats.cost = self.stats.cost \
                 + self.cost_model.write_row().scaled(act.n_rf)
@@ -565,42 +569,44 @@ class PudIsa:
         """
         n = act.n_rf
         base, _is_ref = _base_op(op.lower())
-        # reference block: N-1 constants + one Frac row (§6.1.2)
-        if ref_row is None:
-            const = 1.0 if base == "and" else 0.0
-            self.sim.fill_rows(self.f_sub, act.rows_f[:-1], const,
-                               cols=self._f_sl)
-            self.stats.writes += n - 1
-            # keep stats.cost consistent with the WR commands just issued
-            # (clone_word charges the resident path's ref staging likewise)
-            self.stats.cost = self.stats.cost \
-                + self.cost_model.write_row().scaled(n - 1)
-        else:
-            for r in act.rows_f[:-1]:
-                self.clone_word(self.f_sub, int(ref_row), int(r))
-        self.sim.frac_row(self.f_sub, act.rows_f[-1])
-        self.stats.fracs += 1
-        # compute block: clones in place, host words in one strided scatter
-        if isinstance(sources, tuple) and sources[0] == "write_stack":
-            stack = self._stack_words(sources[1])
-            n_wr = stack.shape[-2]
-            self.sim.write_cols_multi(self.l_sub, act.rows_l[:n_wr],
-                                      self._l_sl, stack)
-            self.stats.writes += n_wr
-        else:
-            wr_rows, wr_bits = [], []
-            for i, (kind, payload) in enumerate(sources):
-                if kind == "clone":
-                    self.clone_word(self.l_sub, int(payload),
-                                    int(act.rows_l[i]))
-                else:
-                    wr_rows.append(int(act.rows_l[i]))
-                    wr_bits.append(payload)
-            if wr_rows:
-                self.sim.write_cols_multi(self.l_sub, wr_rows, self._l_sl,
-                                          self._stack_words(wr_bits))
-                self.stats.writes += len(wr_rows)
-            n_wr = len(wr_rows)
+        # staging: everything before the APA (which ``sim.apa`` times)
+        with tracing.span("isa.stage"):
+            # reference block: N-1 constants + one Frac row (§6.1.2)
+            if ref_row is None:
+                const = 1.0 if base == "and" else 0.0
+                self.sim.fill_rows(self.f_sub, act.rows_f[:-1], const,
+                                   cols=self._f_sl)
+                self.stats.writes += n - 1
+                # keep stats.cost consistent with the WR commands just issued
+                # (clone_word charges the resident path's ref staging likewise)
+                self.stats.cost = self.stats.cost \
+                    + self.cost_model.write_row().scaled(n - 1)
+            else:
+                for r in act.rows_f[:-1]:
+                    self.clone_word(self.f_sub, int(ref_row), int(r))
+            self.sim.frac_row(self.f_sub, act.rows_f[-1])
+            self.stats.fracs += 1
+            # compute block: clones in place, host words in one strided scatter
+            if isinstance(sources, tuple) and sources[0] == "write_stack":
+                stack = self._stack_words(sources[1])
+                n_wr = stack.shape[-2]
+                self.sim.write_cols_multi(self.l_sub, act.rows_l[:n_wr],
+                                          self._l_sl, stack)
+                self.stats.writes += n_wr
+            else:
+                wr_rows, wr_bits = [], []
+                for i, (kind, payload) in enumerate(sources):
+                    if kind == "clone":
+                        self.clone_word(self.l_sub, int(payload),
+                                        int(act.rows_l[i]))
+                    else:
+                        wr_rows.append(int(act.rows_l[i]))
+                        wr_bits.append(payload)
+                if wr_rows:
+                    self.sim.write_cols_multi(self.l_sub, wr_rows, self._l_sl,
+                                              self._stack_words(wr_bits))
+                    self.stats.writes += len(wr_rows)
+                n_wr = len(wr_rows)
         self.sim.op_boolean(op, self.sim.global_addr(self.f_sub, rf),
                             self.sim.global_addr(self.l_sub, rl),
                             random_pattern=random_pattern)

@@ -79,6 +79,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from .. import tracing
 from . import analog as A
 from . import decoder as DEC
 from .analog import AnalogParams
@@ -128,6 +129,21 @@ def _norm_ppf(q):
         out[hi] = -((((((c[0]*r+c[1])*r+c[2])*r+c[3])*r+c[4])*r+c[5]) /
                     ((((d[0]*r+d[1])*r+d[2])*r+d[3])*r+1))
     return out
+
+
+def _resolve_call(com_cells, ref_cells, static, normals, uniforms,
+                  **scalars) -> np.ndarray:
+    """The sense-amp kernel entry on host arrays, its decisions brought
+    back to the host: the resolve boundary's host-to-device copies,
+    kernel and copy back (timed as ``sim.resolve_call``; the copies in
+    are counted as ``resolve.h2d_bytes``)."""
+    from ..kernels import ops as kops
+    tracing.count("resolve.h2d_bytes",
+                  com_cells.nbytes + ref_cells.nbytes + static.nbytes
+                  + normals.nbytes + uniforms.nbytes)
+    with tracing.span("sim.resolve_call"):
+        return np.asarray(kops.senseamp_resolve_trials(
+            com_cells, ref_cells, static, normals, uniforms, **scalars))
 
 
 @dataclass(frozen=True)
@@ -575,33 +591,32 @@ class BankSim:
         :meth:`_resolve` draw-for-draw, so at one seed the two backends
         differ only by float32 re-association at the comparator threshold.
         """
-        from ..kernels import ops as kops
         p = self.params
-        dv, s, shift, static, pf = self._resolve_params(
-            stripe, op, n, regions=regions, random_pattern=random_pattern)
-        shape = com_cells.shape[:1] + com_cells.shape[2:]      # (T, w)
-        nz = rng.standard_normal(shape, dtype=self._noise_dtype)
-        if self.batched:
-            u = rng.random(shape, dtype=self._noise_dtype)
-            # same single-uniform flip/coin decisions as the numpy path:
-            # the kernel's coin is (un[1] < 0.5), so encode it as 0/1
-            coin = np.where(u < 0.5 * pf, np.float32(0.0), np.float32(1.0))
-            un = np.stack([u.astype(np.float32, copy=False), coin])
-        else:
-            flip_u = rng.random(shape, dtype=self._noise_dtype)
-            coin_u = rng.random(shape, dtype=self._noise_dtype)
-            un = np.stack([flip_u, coin_u]).astype(np.float32, copy=False)
-        trial_sigma = math.sqrt(max(1.0 - STATIC_SPLIT ** 2, 0.0)) * s
+        with tracing.span("sim.resolve_prep"):
+            dv, s, shift, static, pf = self._resolve_params(
+                stripe, op, n, regions=regions, random_pattern=random_pattern)
+            shape = com_cells.shape[:1] + com_cells.shape[2:]      # (T, w)
+            nz = rng.standard_normal(shape, dtype=self._noise_dtype)
+            if self.batched:
+                u = rng.random(shape, dtype=self._noise_dtype)
+                # same single-uniform flip/coin decisions as the numpy path:
+                # the kernel's coin is (un[1] < 0.5), so encode it as 0/1
+                coin = np.where(u < 0.5 * pf, np.float32(0.0), np.float32(1.0))
+                un = np.stack([u.astype(np.float32, copy=False), coin])
+            else:
+                flip_u = rng.random(shape, dtype=self._noise_dtype)
+                coin_u = rng.random(shape, dtype=self._noise_dtype)
+                un = np.stack([flip_u, coin_u]).astype(np.float32, copy=False)
+            trial_sigma = math.sqrt(max(1.0 - STATIC_SPLIT ** 2, 0.0)) * s
+            static = static.astype(np.float32, copy=False)
+            nz = nz.astype(np.float32, copy=False)
         # numpy threshold: margin + static + noise > -(dv - shift - delta_v)
         # kernel threshold: margin_k - shift_k + static + noise > 0
-        out = kops.senseamp_resolve_trials(
-            com_cells, ref_cells,
-            static.astype(np.float32, copy=False),
-            nz.astype(np.float32, copy=False), un,
+        return _resolve_call(
+            com_cells, ref_cells, static, nz, un,
             u_com=float(u_com), u_ref=float(u_ref),
             shift=float(shift + p.delta_v - dv), pf=float(pf),
             trial_sigma=float(trial_sigma))
-        return np.asarray(out)
 
     def _maj_restore(self, sub: int, rows, cols: slice,
                      rng: np.random.Generator) -> None:
@@ -619,6 +634,7 @@ class BankSim:
         out = (v > 0.0).astype(np.float32)
         arr[:, rows, cols] = out[:, None, :]
 
+    @tracing.traced("sim.apa")
     def apa(self, rf_global: int, rl_global: int, *,
             first_act_restored: bool = False,
             random_pattern: bool = True) -> DEC.Activation:

@@ -90,6 +90,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from ..core import compiler as CC
 from ..core.bankarray import BankArray
 from ..core.device import ENERGY_PJ, ActivationSupport, get_module
@@ -575,21 +576,23 @@ class PudEngine:
         """
         if not planes:
             raise ValueError("run_program needs at least one input plane")
-        named = {k: jnp.asarray(v, jnp.uint32) for k, v in planes.items()}
-        shapes = {v.shape for v in named.values()}
-        if len(shapes) != 1:
-            raise ValueError(f"input planes disagree on shape: {shapes}")
-        (shape,) = shapes
-        missing = {i.name for i in prog.instrs if i.op == "input"} \
-            - named.keys()
-        if missing:       # validate before metering: a failed run must not
-            raise ValueError(   # inflate the offload report
-                f"program inputs missing from planes: {sorted(missing)}")
-        r, c = shape
-        self._meter_program(prog, r * c * 32)
-        if self.backend == "dram":
-            return self._dram_run_program(prog, named, shape)
-        return self._planes_run_program(prog, named, shape)
+        with tracing.span("engine.run_program"):
+            named = {k: jnp.asarray(v, jnp.uint32) for k, v in planes.items()}
+            shapes = {v.shape for v in named.values()}
+            if len(shapes) != 1:
+                raise ValueError(f"input planes disagree on shape: {shapes}")
+            (shape,) = shapes
+            missing = {i.name for i in prog.instrs if i.op == "input"} \
+                - named.keys()
+            if missing:       # validate before metering: a failed run must not
+                raise ValueError(   # inflate the offload report
+                    f"program inputs missing from planes: {sorted(missing)}")
+            r, c = shape
+            with tracing.span("engine.meter"):
+                self._meter_program(prog, r * c * 32)
+            if self.backend == "dram":
+                return self._dram_run_program(prog, named, shape)
+            return self._planes_run_program(prog, named, shape)
 
     def _planes_run_program(self, prog: CC.Program, planes, shape):
         """Whole-plane program execution (jnp ops or Pallas kernels)."""
@@ -602,12 +605,16 @@ class PudEngine:
                 fill = jnp.uint32(0xFFFFFFFF if i.value else 0)
                 regs[i.dst] = jnp.full(shape, fill, jnp.uint32)
             elif i.op == "not":
-                regs[i.dst] = (kops.bitwise_not(regs[i.srcs[0]])
-                               if pallas else ~regs[i.srcs[0]])
+                with tracing.span("engine.kernel"):
+                    regs[i.dst] = (kops.bitwise_not(regs[i.srcs[0]])
+                                   if pallas else ~regs[i.srcs[0]])
             elif i.op in ("and", "or", "nand", "nor"):
-                stack = jnp.stack([regs[s] for s in i.srcs])
-                regs[i.dst] = (kops.nary_bitwise(stack, i.op) if pallas
-                               else kops.ref.nary_bitwise(i.op, stack))
+                with tracing.span("engine.stack"):
+                    stack = jnp.stack([regs[s] for s in i.srcs])
+                tracing.count("engine.stack_bytes", stack.nbytes)
+                with tracing.span("engine.kernel"):
+                    regs[i.dst] = (kops.nary_bitwise(stack, i.op) if pallas
+                                   else kops.ref.nary_bitwise(i.op, stack))
             else:
                 raise ValueError(i.op)
         return {k: regs[v] for k, v in prog.outputs.items()}
