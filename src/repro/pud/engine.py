@@ -109,6 +109,15 @@ def _adder_program(k: int) -> CC.Program:
     return CC.compile_expr(CC.adder_exprs(k))
 
 
+@jax.jit
+def stack_planes(*planes: jax.Array) -> jax.Array:
+    """Equal-shape ``(R, C)`` planes -> one ``(n, R, C)`` operand stack in
+    one device program (a fused copy).  jit's own cache keys it on the
+    arity and the plane shape; an eager ``jnp.stack`` would dispatch one
+    ``broadcast_in_dim`` per plane and then a ``concatenate``."""
+    return jnp.stack(planes)
+
+
 @dataclass
 class OffloadReport:
     """Accumulated in-DRAM vs CPU-baseline cost of engine traffic.
@@ -610,7 +619,7 @@ class PudEngine:
                                    if pallas else ~regs[i.srcs[0]])
             elif i.op in ("and", "or", "nand", "nor"):
                 with tracing.span("engine.stack"):
-                    stack = jnp.stack([regs[s] for s in i.srcs])
+                    stack = stack_planes(*(regs[s] for s in i.srcs))
                 tracing.count("engine.stack_bytes", stack.nbytes)
                 with tracing.span("engine.kernel"):
                     regs[i.dst] = (kops.nary_bitwise(stack, i.op) if pallas
