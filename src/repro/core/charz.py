@@ -643,73 +643,84 @@ def mc_program_success(program: str | CC.Program, *, trials: int = 200,
     if pol.is_resident and not batched:
         raise ValueError("resident execution requires batched=True")
     banks = _check_banks(banks, batched=batched)
-    if batched:
-        groups = max(1, min(groups, trials))
-        tg = max(1, -(-trials // groups))
-        arr = BankArray(module or get_module(), banks=banks,
-                        row_bits=row_bits, seed=seed, temp_c=temp_c,
-                        error_model="analog", trials=tg,
-                        track_unshared=False)
-        if _use_fused(fused, arr.module, banks, dealer,
-                      resident=pol.is_resident):
+    label = program if isinstance(program, str) else "custom"
+    with tracing.span("charz.estimate", op="program", program=label,
+                      seed=seed, banks=banks):
+        if batched:
+            groups = max(1, min(groups, trials))
+            tg = max(1, -(-trials // groups))
+            with tracing.span("charz.chip"):
+                arr = BankArray(module or get_module(), banks=banks,
+                                row_bits=row_bits, seed=seed, temp_c=temp_c,
+                                error_model="analog", trials=tg,
+                                track_unshared=False)
+            if _use_fused(fused, arr.module, banks, dealer,
+                          resident=pol.is_resident):
 
-            def run_round(fisa, r):
-                nonlocal ok, tot
-                k = fisa.n_banks
-                ins = {}
-                draws = [{m: _random_bits(rng, (tg, fisa.width))
-                          for m in names} for _b in range(k)]
-                for m in names:
-                    ins[m] = np.concatenate([d[m] for d in draws])
-                got = CC.run_sim(prog, ins, fisa, trials=k * tg,
-                                 resident=pol)
-                want = CC.run_ideal(prog, ins, width=fisa.width)
-                ok += sum(int(np.sum(got[o] == want[o]))
-                          for o in prog.outputs)
-                tot += sum(got[o].size for o in prog.outputs)
+                def run_round(fisa, r):
+                    nonlocal ok, tot
+                    k = fisa.n_banks
+                    ins = {}
+                    draws = [{m: _random_bits(rng, (tg, fisa.width))
+                              for m in names} for _b in range(k)]
+                    for m in names:
+                        ins[m] = np.concatenate([d[m] for d in draws])
+                    got = CC.run_sim(prog, ins, fisa, trials=k * tg,
+                                     resident=pol)
+                    want = CC.run_ideal(prog, ins, width=fisa.width)
+                    ok += sum(int(np.sum(got[o] == want[o]))
+                              for o in prog.outputs)
+                    tot += sum(got[o].size for o in prog.outputs)
+                    tracing.count("charz.trials", k * tg)
 
-            _fused_mc_rounds(arr, groups, run_round)
+                _fused_mc_rounds(arr, groups, run_round)
+                _fill_stats(stats, arr, groups, tg)
+                return ok / tot
+            decisions = None
+            for bank_g in _deal_groups(arr, groups, dealer):
+                with tracing.span("charz.chip"):
+                    isa = arr.isa(bank_g)
+                plan = None
+                if pol.is_resident:
+                    isa.sim.recycle_rows()  # resident runs re-stage all state
+                    if pol is ResidentPolicy.SCHEDULED:
+                        if isa.bank == 0:
+                            # the search result is cached: group 1 pays it,
+                            # later groups replan with frozen decisions
+                            plan = CC.schedule_resident(prog, isa,
+                                                        policy="scheduled")
+                        else:
+                            # sibling banks replay bank 0's decisions (plans
+                            # are seed-dependent; decisions are not)
+                            if decisions is None:
+                                decisions = CC.shared_schedule_decisions(
+                                    prog, arr.isa(0))
+                            plan = CC.schedule_resident(prog, isa,
+                                                        policy="scheduled",
+                                                        _fixed=decisions)
+                ins = {n: _random_bits(rng, (tg, isa.width)) for n in names}
+                got = CC.run_sim(prog, ins, isa, trials=tg, resident=pol,
+                                 plan=plan)
+                want = CC.run_ideal(prog, ins, width=isa.width)
+                ok += sum(int(np.sum(got[k] == want[k]))
+                          for k in prog.outputs)
+                tot += sum(got[k].size for k in prog.outputs)
+                tracing.count("charz.trials", tg)
             _fill_stats(stats, arr, groups, tg)
             return ok / tot
-        decisions = None
-        for bank_g in _deal_groups(arr, groups, dealer):
-            isa = arr.isa(bank_g)
-            plan = None
-            if pol.is_resident:
-                isa.sim.recycle_rows()  # resident runs re-stage all state
-                if pol is ResidentPolicy.SCHEDULED:
-                    if isa.bank == 0:
-                        # the search result is cached: group 1 pays it,
-                        # later groups replan with frozen decisions
-                        plan = CC.schedule_resident(prog, isa,
-                                                    policy="scheduled")
-                    else:
-                        # sibling banks replay bank 0's decisions (plans
-                        # are seed-dependent; decisions are not)
-                        if decisions is None:
-                            decisions = CC.shared_schedule_decisions(
-                                prog, arr.isa(0))
-                        plan = CC.schedule_resident(prog, isa,
-                                                    policy="scheduled",
-                                                    _fixed=decisions)
-            ins = {n: _random_bits(rng, (tg, isa.width)) for n in names}
-            got = CC.run_sim(prog, ins, isa, trials=tg, resident=pol,
-                             plan=plan)
+        tracing.count("charz.trials", trials)
+        with tracing.span("charz.chip"):
+            sim = BankSim(module or get_module(), row_bits=row_bits,
+                          seed=seed, temp_c=temp_c, error_model="analog")
+            isa = PudIsa(sim)
+        for _t in range(trials):
+            ins = {n: _random_bits(rng, (isa.width,)) for n in names}
+            got = CC.run_sim(prog, ins, isa)
             want = CC.run_ideal(prog, ins, width=isa.width)
-            ok += sum(int(np.sum(got[k] == want[k])) for k in prog.outputs)
+            ok += sum(int(np.sum(got[k] == want[k]))
+                      for k in prog.outputs)
             tot += sum(got[k].size for k in prog.outputs)
-        _fill_stats(stats, arr, groups, tg)
         return ok / tot
-    sim = BankSim(module or get_module(), row_bits=row_bits, seed=seed,
-                  temp_c=temp_c, error_model="analog")
-    isa = PudIsa(sim)
-    for _t in range(trials):
-        ins = {n: _random_bits(rng, (isa.width,)) for n in names}
-        got = CC.run_sim(prog, ins, isa)
-        want = CC.run_ideal(prog, ins, width=isa.width)
-        ok += sum(int(np.sum(got[k] == want[k])) for k in prog.outputs)
-        tot += sum(got[k].size for k in prog.outputs)
-    return ok / tot
 
 
 # ---------------------------------------------------------------------------

@@ -47,6 +47,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from .. import tracing
 from .isa import CostModel, OpCost, PudIsa, metric_index
 from .policy import ResidentPolicy  # canonical resident spelling
 
@@ -1186,6 +1187,20 @@ def schedule_resident(prog: Program, isa: PudIsa, *,
     >>> int(out["out"].sum())               # 1 ^ 0 = 1 on every lane
     32
     """
+    with tracing.span("compiler.schedule", policy=policy,
+                      fixed=_fixed is not None):
+        return _schedule_resident(prog, isa, policy=policy, carry=carry,
+                                  pins=pins, pin_inputs=pin_inputs,
+                                  duplicate=duplicate, objective=objective,
+                                  verify=verify, _fixed=_fixed)
+
+
+def _schedule_resident(prog: Program, isa: PudIsa, *, policy: str,
+                       carry: dict | None, pins: dict | None,
+                       pin_inputs: bool, duplicate: bool | None,
+                       objective: str, verify: bool | None,
+                       _fixed: tuple | None) -> ResidentPlan:
+    """The search (or frozen replay) behind :func:`schedule_resident`."""
     if policy not in ("greedy", "scheduled"):
         raise ValueError(f"unknown resident policy {policy!r}")
     if duplicate is None:
@@ -1400,10 +1415,12 @@ class _ResidentExec:
         bits = host[reg]
         return (1 - bits).astype(np.uint8) if neg else bits
 
+    @tracing.traced("resident.exec")
     def run(self) -> dict[str, np.ndarray]:
         isa = self.isa
         host: dict[int, np.ndarray] = {}
         out: dict[str, np.ndarray] = {}
+        staged = 0                     # rows host-written into the bank
         for st in self.plan.steps:
             if st.kind == "host":
                 i = st.instr
@@ -1430,6 +1447,7 @@ class _ResidentExec:
                     isa.clone_word(self._sub(m[1]), m[2], m[3])
                 elif m[0] == "fill":
                     isa.fill_const_row(self._sub(m[1]), m[2], m[3])
+                    staged += 1
                 elif m[0] == "spill":
                     _, reg, side, row, negf = m
                     bits = isa.read_result_word(self._sub(side), row)
@@ -1441,10 +1459,12 @@ class _ResidentExec:
                     _, reg, row, negf = m
                     isa.stage_word(isa.l_sub, row,
                                    self._word(host, reg, negf))
+                    staged += 1
             if st.kind == "bool":
                 sources = [s if s[0] == "clone"
                            else ("write", self._word(host, s[1], s[2]))
                            for s in st.sources]
+                staged += sum(s[0] == "write" for s in sources)
                 isa.exec_nary(st.exec_op, st.rf, st.rl, st.act, sources,
                               ref_row=st.ref_row)
                 if st.dup:
@@ -1453,7 +1473,10 @@ class _ResidentExec:
                 s = st.sources[0]
                 source = s if s[0] == "clone" \
                     else ("write", self._word(host, s[1], s[2]))
+                staged += st.act.n_rf if s[0] == "write" else 0
                 isa.exec_not(st.rf, st.rl, st.act, source)
+        tracing.count("resident.h2d_bytes",
+                      staged * (isa.sim.geom.row_bits // 8))
         return out
 
 

@@ -401,7 +401,8 @@ class PudIsa:
         primitive): no bus traffic, 2 ACTs.  A no-op when src == dst."""
         if src == dst:
             return
-        self.sim.rowclone(sub, src, dst)
+        with tracing.span("resident.rowclone"):
+            self.sim.rowclone(sub, src, dst)
         self.stats.rowclones += 1
         self.stats.cost = self.stats.cost + self.cost_model.rowclone()
 

@@ -35,6 +35,8 @@ SPAN_NAMES = (
     "isa.stage", "isa.readout", "isa.inventory",
     # simulator and the resolve boundary (core/simulator.py, core/fused.py)
     "sim.apa", "sim.resolve_prep", "sim.resolve_call",
+    # planner and resident executor (core/compiler.py, core/isa.py)
+    "compiler.schedule", "resident.exec", "resident.rowclone",
     # engine (pud/engine.py)
     "engine.run_program", "engine.meter", "engine.stack", "engine.kernel",
 )
