@@ -98,6 +98,34 @@ STATIC_SPLIT = 0.8
 #: of them, so the simulator models a small independent failure floor.
 ROWCLONE_FAIL_P = 2e-6
 
+#: (mantissa bits, word bits) of ``Generator.random``'s uniforms
+_UNIFORM_BITS = {np.dtype(np.float32): (24, 32),
+                 np.dtype(np.float64): (53, 64)}
+
+
+def _uniform_hits(rng: np.random.Generator, size: int, dtype,
+                  p: float) -> np.ndarray:
+    """Flat indices where ``rng.random(size, dtype) < p``, bit for bit,
+    read off the generator's raw words without making the floats.
+
+    NumPy's PCG64 builds a float32 uniform as ``(u32 >> 8) * 2**-24``,
+    handing out each 64-bit word's low half, then its high half, and a
+    float64 as ``(u64 >> 11) * 2**-53``.  So ``u < p`` holds exactly when
+    ``word < ceil(p * 2**m) << (bits - m)``, with ``p`` cast as the float
+    comparison casts it (a Python float to ``dtype``; a NumPy float64
+    promotes the comparison to float64)."""
+    m, bits = _UNIFORM_BITS[np.dtype(dtype)]
+    pc = float(np.asarray(p, dtype=np.result_type(dtype, p)))
+    k = math.ceil(min(pc, 1.0) * 2.0 ** m)
+    if k >= 1 << m:
+        return np.arange(size)
+    if bits == 32:
+        words = rng.bit_generator.random_raw((size + 1) // 2) \
+            .astype("<u8", copy=False).view("<u4")[:size]
+    else:
+        words = rng.bit_generator.random_raw(size)
+    return np.flatnonzero(words < words.dtype.type(k << (bits - m)))
+
 
 def _norm_ppf(q):
     """Acklam's inverse normal CDF approximation (max abs err ~1.15e-9)."""
@@ -477,19 +505,33 @@ class BankSim:
         *noisy*: each destination cell independently flips with probability
         ``rowclone_fail_p`` (the source, fully restored by the first ACT,
         is unaffected) — the resident-register executor chains many clones,
-        so the floor is modeled rather than assumed away.
+        so the floor is modeled rather than assumed away.  The flips are
+        read off the copy's generator words and only the hit cells are
+        touched (:func:`_uniform_hits`).
         """
-        isrc, idst = self._map_rows(sub, [src, dst])
+        rmap = self._rowmap.get(sub)
+        nrows = self.geom.rows_per_subarray
+        if (rmap is not None and isinstance(src, (int, np.integer))
+                and isinstance(dst, (int, np.integer))
+                and 0 <= src < nrows and 0 <= dst < nrows
+                and rmap[src] >= 0 and rmap[dst] >= 0):
+            isrc, idst = rmap[src], rmap[dst]
+        else:
+            isrc, idst = self._map_rows(sub, [src, dst])
         arr = self._cells(sub)
-        restored = (arr[:, isrc] > 0.5).astype(np.float32)
-        copied = restored
+        restored = arr[:, isrc]
+        np.greater(restored, 0.5, out=restored)  # source restored
+        hit = None
         if self.error_model == "analog" and self.rowclone_fail_p > 0.0:
-            rng = self._rng()
-            flip = rng.random(restored.shape,
-                              dtype=self._noise_dtype) < self.rowclone_fail_p
-            copied = np.where(flip, 1.0 - restored, restored)
-        arr[:, idst] = copied
-        arr[:, isrc] = restored  # source restored
+            hit = _uniform_hits(self._rng(), restored.size,
+                                self._noise_dtype, self.rowclone_fail_p)
+        if idst != isrc:
+            copied = arr[:, idst]
+            copied[...] = restored
+            if hit is not None:
+                tracing.count("sim.rowclone_flips", hit.size)
+                trial, col = np.divmod(hit, restored.shape[1])
+                copied[trial, col] = 1.0 - copied[trial, col]
         t = self.timings
         self.log.add("RC", t.tRAS + VIOLATED_TRP_NS + t.tRAS + t.tRP,
                      2 * ENERGY_PJ["act"] + 2 * ENERGY_PJ["pre"],
