@@ -123,7 +123,7 @@ def activation_pattern(module: ModuleConfig, rf: int, rl: int,
         # Samsung: sequential two-row activation only -> 1:1 (NOT with 1 dst)
         u = _pair_hash(rf, rl, seed ^ 0x5E0)
         if u < 0.35:  # sequential activation window hit
-            return Activation(1, 1, (rf,), (rl,))
+            return Activation(1, 1, (int(rf),), (int(rl),))
         return NONE_ACTIVATION
     cum, cats = _category_table(module.max_simultaneous_rows,
                                 module.supports_n2n)

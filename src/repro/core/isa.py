@@ -30,7 +30,7 @@ from . import decoder as DEC
 from .analog import ALL_OPS, _base_op
 from .device import (ENERGY_PJ, ModuleConfig, get_module, timings_for,
                      VIOLATED_TRAS_NS, VIOLATED_TRP_NS)
-from .simulator import BankSim
+from .simulator import BankSim, CapabilityError, FusedGeometryError, PerBank
 
 
 # ---------------------------------------------------------------------------
@@ -89,10 +89,6 @@ class PairInventory:
     def coverage(self, n_rf: int, n_rl: int) -> float:
         n = self.module.geometry.rows_per_subarray
         return len(self.pairs(n_rf, n_rl)) / float(n * n)
-
-
-class CapabilityError(RuntimeError):
-    """The module cannot express the requested activation/op."""
 
 
 @lru_cache(maxsize=16)
@@ -311,6 +307,12 @@ class PudIsa:
     Convention: R_F side = ``f_sub`` (reference rows for Boolean ops, source
     row for NOT); R_L side = ``l_sub = f_sub + 1`` (compute rows / NOT
     destinations).  Logical words are ``shared_w`` bits.
+
+    Planning runs over the sim's bank axis: each bank walks its own pair
+    inventory and cursor, and the banks must agree on the activation
+    geometry.  On a one-bank sim the row handles are ints and the
+    activation the decoder's; ``repro.core.fused.FusedPudIsa`` hands out
+    per-bank handles instead.
     """
 
     def __init__(self, sim: BankSim, *, f_sub: int = 0,
@@ -325,7 +327,9 @@ class PudIsa:
         self.l_sub = f_sub + 1 if l_sub is None else l_sub
         if abs(self.f_sub - self.l_sub) != 1:
             raise ValueError("PudIsa needs neighboring subarrays")
-        self.inv = inventory_for(sim.module, sim.seed)
+        #: each bank's pair inventory (its chip's decoder map)
+        self.invs = [inventory_for(sim.module, s) for s in sim.bank_seeds]
+        self.inv = self.invs[0]
         self.cost_model = CostModel(sim.module, row_bits=sim.geom.row_bits)
         self.stats = IsaStats()
         lo = min(self.f_sub, self.l_sub)
@@ -335,10 +339,16 @@ class PudIsa:
         # same column sets as contiguous storage-layout slices (see
         # BankSim stripe-major layout)
         _lo, self._f_sl, self._l_sl = sim._col_slices(self.f_sub, self.l_sub)
-        self._pair_cursor: dict[tuple[int, int], int] = {}
+        #: each bank's pair-walk cursors, keyed by (n_rf, n_rl)
+        self._bank_cursors: list[dict] = [{} for _ in self.invs]
         #: the most recent ResidentPlan executed through this ISA (set by
         #: compiler._run_sim_resident; None until a resident run happens)
         self.last_resident_plan = None
+
+    @property
+    def _pair_cursor(self) -> dict[tuple[int, int], int]:
+        """Bank 0's pair-walk cursors."""
+        return self._bank_cursors[0]
 
     # ---------------- word packing ----------------
     @property
@@ -433,52 +443,92 @@ class PudIsa:
         return self._unpack(sub, row, side)
 
     # ---------------- pair selection ----------------
-    def _next_pair(self, n_rf: int, n_rl: int) -> tuple[int, int]:
+    def _next_pair(self, n_rf: int, n_rl: int,
+                   bank: int = 0) -> tuple[int, int]:
         """Deterministic but *scrambled* pair iteration: consecutive ops use
         pairs spread uniformly over the subarray (and hence over the
-        distance regions), matching the paper's row-sweeping protocol."""
+        distance regions), matching the paper's row-sweeping protocol.
+        Each bank walks its own inventory, keyed by its own seed."""
         key = (n_rf, n_rl)
-        k = self._pair_cursor.get(key, 0)
-        self._pair_cursor[key] = k + 1
-        n_pairs = max(len(self.inv.pairs(n_rf, n_rl)), 1)
-        scrambled = DEC._mix64(k * 0x9E3779B97F4A7C15 + self.sim.seed)
-        return self.inv.choose(n_rf, n_rl, scrambled % n_pairs)
+        cur = self._bank_cursors[bank]
+        k = cur.get(key, 0)
+        cur[key] = k + 1
+        inv = self.invs[bank]
+        n_pairs = max(len(inv.pairs(n_rf, n_rl)), 1)
+        scrambled = DEC._mix64(k * 0x9E3779B97F4A7C15
+                               + self.sim.bank_seeds[bank])
+        return inv.choose(n_rf, n_rl, scrambled % n_pairs)
+
+    def _plan_pairs(self, n_rf: int, n_rl: int, pair,
+                    pair_index: int | None) -> list[tuple[int, int]]:
+        """Each bank's (R_F, R_L) rows: ``pair`` pins them (one pair for
+        every bank, or one per bank), ``pair_index`` picks from each
+        bank's inventory, and by default each bank walks on."""
+        n_banks = self.sim.n_banks
+        if pair is not None:
+            pair = list(pair.vals if isinstance(pair, PerBank) else pair)
+            if len(pair) == 2 and all(
+                    isinstance(x, (int, np.integer)) for x in pair):
+                return [(int(pair[0]), int(pair[1]))] * n_banks
+            if len(pair) != n_banks:
+                raise ValueError(f"need one (rf, rl) pair per bank "
+                                 f"({n_banks}), got {len(pair)}")
+            return [(int(rf), int(rl)) for rf, rl in pair]
+        if pair_index is not None:
+            return [inv.choose(n_rf, n_rl, pair_index) for inv in self.invs]
+        return [self._next_pair(n_rf, n_rl, b) for b in range(n_banks)]
+
+    def _activation(self, bank: int, pair: tuple[int, int]) -> DEC.Activation:
+        return DEC.activation_pattern(self.sim.module, *pair,
+                                      seed=self.sim.bank_seeds[bank])
+
+    def _handles(self, pairs: list, acts: list):
+        """-> (rf, rl, activation) of a planned op: bank 0's row ints and
+        decoder Activation (a one-bank ISA)."""
+        return (*pairs[0], acts[0])
+
+    @staticmethod
+    def _one_for_all(vals: list, what: str):
+        """The value every bank agrees on."""
+        if len(set(vals)) != 1:
+            raise FusedGeometryError(f"{what} differs across banks: {vals}")
+        return vals[0]
 
     # ---------------- logical ops ----------------
     def not_activation(self, n_dst: int) -> int:
         """R_F-side row count for a NOT with ``n_dst`` destinations: the
         smallest available (least drive load, Obs. 5)."""
-        for n_rf in (max(n_dst // 2, 1), n_dst):
-            if len(self.inv.pairs(n_rf, n_dst)):
-                return n_rf
-        raise CapabilityError(f"no activation with {n_dst} dst rows")
+        n_rfs = []
+        for inv in self.invs:
+            for n_rf in (max(n_dst // 2, 1), n_dst):
+                if len(inv.pairs(n_rf, n_dst)):
+                    n_rfs.append(n_rf)
+                    break
+            else:
+                raise CapabilityError(f"no activation with {n_dst} dst rows")
+        return self._one_for_all(n_rfs, "NOT source-row count")
 
     def plan_not(self, n_dst: int = 1, *, pair_index: int | None = None,
-                 pair: tuple[int, int] | None = None):
+                 pair=None):
         """Pair selection for a NOT: -> (rf, rl, activation)."""
         n_rf = self.not_activation(n_dst)
-        if pair is not None:
-            rf, rl = pair
-        elif pair_index is not None:
-            rf, rl = self.inv.choose(n_rf, n_dst, pair_index)
-        else:
-            rf, rl = self._next_pair(n_rf, n_dst)
-        act = DEC.activation_pattern(self.sim.module, rf, rl,
-                                     seed=self.sim.seed)
-        if act.n_rf == 0 and pair is None and pair_index is None:
+        pairs = self._plan_pairs(n_rf, n_dst, pair, pair_index)
+        acts = [self._activation(b, p) for b, p in enumerate(pairs)]
+        if pair is None and pair_index is None:
             # sequential-activation modules (Samsung) miss on ~2/3 of the
             # address pairs the inventory lists: sweep on, like the paper
-            for _ in range(63):
-                rf, rl = self._next_pair(n_rf, n_dst)
-                act = DEC.activation_pattern(self.sim.module, rf, rl,
-                                             seed=self.sim.seed)
-                if act.n_rf:
-                    break
-        if act.n_rf == 0:
-            raise CapabilityError(
-                f"address pair ({rf}, {rl}) yields no simultaneous "
-                f"activation on {self.sim.module.name}")
-        return rf, rl, act
+            for b in range(len(acts)):
+                for _ in range(63):
+                    if acts[b].n_rf:
+                        break
+                    pairs[b] = self._next_pair(n_rf, n_dst, b)
+                    acts[b] = self._activation(b, pairs[b])
+        for b, act in enumerate(acts):
+            if act.n_rf == 0:
+                raise CapabilityError(
+                    f"address pair {pairs[b]} yields no simultaneous "
+                    f"activation on {self.sim.module.name} (bank {b})")
+        return self._handles(pairs, acts)
 
     def exec_not(self, rf: int, rl: int, act: DEC.Activation,
                  source) -> tuple[int, int]:
@@ -505,7 +555,7 @@ class PudIsa:
         self.stats.apas += 1
         self.stats.ops += 1
         self.stats.cost = self.stats.cost + self.cost_model.op_not(act.n_rl)
-        return int(act.rows_l[0]), int(act.rows_f[0])
+        return act.rows_l[0], act.rows_f[0]
 
     def op_not(self, bits: np.ndarray, *, n_dst: int = 1,
                pair_index: int | None = None,
@@ -521,7 +571,7 @@ class PudIsa:
         return self._result_word(self.l_sub, res_row, "l")
 
     def plan_nary(self, op: str, n: int, *, pair_index: int | None = None,
-                  pair: tuple[int, int] | None = None):
+                  pair=None):
         """Capability checks + pair selection for an n-ary Boolean op.
 
         -> (n_hw, rf, rl, activation): the decoder only expresses
@@ -536,21 +586,23 @@ class PudIsa:
             raise CapabilityError(
                 f"{n}-input ops exceed module capability "
                 f"({self.sim.module.max_inputs})")
-        n_hw = n
-        while n_hw <= 16 and len(self.inv.pairs(n_hw, n_hw)) == 0:
-            n_hw += n_hw % 2 or 1   # next even, then doubles via pairs check
-        if len(self.inv.pairs(n_hw, n_hw)) == 0:
-            raise CapabilityError(f"no >= {n}:{n} pairs on this module")
-        if pair is not None:
-            rf, rl = pair
-        elif pair_index is not None:
-            rf, rl = self.inv.choose(n_hw, n_hw, pair_index)
-        else:
-            rf, rl = self._next_pair(n_hw, n_hw)
-        act = DEC.activation_pattern(self.sim.module, rf, rl,
-                                     seed=self.sim.seed)
-        assert act.n_rf == n_hw and act.n_rl == n_hw
-        return n_hw, rf, rl, act
+        n_hws = []
+        for inv in self.invs:
+            n_hw = n
+            while n_hw <= 16 and len(inv.pairs(n_hw, n_hw)) == 0:
+                n_hw += n_hw % 2 or 1   # next even, then doubles via pairs check
+            if len(inv.pairs(n_hw, n_hw)) == 0:
+                raise CapabilityError(f"no >= {n}:{n} pairs on this module")
+            n_hws.append(n_hw)
+        n_hw = self._one_for_all(n_hws, "hardware fan-in")
+        pairs = self._plan_pairs(n_hw, n_hw, pair, pair_index)
+        acts = [self._activation(b, p) for b, p in enumerate(pairs)]
+        for b, a in enumerate(acts):
+            if a.n_rf != n_hw or a.n_rl != n_hw:
+                raise FusedGeometryError(
+                    f"pair {pairs[b]} activates {a.n_rf}:{a.n_rl} on bank "
+                    f"{b}, wanted {n_hw}:{n_hw}")
+        return (n_hw, *self._handles(pairs, acts))
 
     def exec_nary(self, op: str, rf: int, rl: int, act: DEC.Activation,
                   sources, *, ref_row: int | None = None,
@@ -615,7 +667,7 @@ class PudIsa:
         self.stats.ops += 1
         self.stats.cost = self.stats.cost + self.cost_model.boolean(n) \
             + self.cost_model.write_row().scaled(n_wr)
-        return int(act.rows_l[0]), int(act.rows_f[0])
+        return act.rows_l[0], act.rows_f[0]
 
     def nary_op(self, op: str, operands: list[np.ndarray], *,
                 pair_index: int | None = None,

@@ -53,6 +53,44 @@ command-sequence episodes on the *same* chip (e.g. the chunk-blocked
 episode via :meth:`reseed_noise`, so error patterns never repeat across
 blocks while the chip's decoder map and static offsets stay put.
 
+Bank axis
+---------
+A sim can also carry N independent banks (chip identities) stacked onto
+the trial axis: ``(N*T, rows, row_bits)`` state, bank b owning trials
+``b*T .. (b+1)*T - 1``.  ``BankSim(...)`` is the one-bank case;
+``repro.core.fused.FusedBankSim`` builds N > 1.  Every command runs once
+for all banks, and per bank it is bit for bit the command a one-bank sim
+of that bank would run (gated in ``tests/test_fused.py`` and
+``benchmarks/diff_bench.py``):
+
+* *RNG consumption*: each command opens one generator per bank, seeded
+  ``SeedSequence([noise_seed_b, 0x7A1A1, counter_b])`` from the bank's own
+  noise seed and command counter, and draws each bank's ``(T, ...)``
+  slice from it (:class:`_BankRng`), so each bank sees the call sequence
+  its own sim would.
+* *Chip identity*: static SA latents are drawn per bank seed and stacked
+  ``(N, w)``; decoder activations are evaluated per bank seed.
+* *Analog scalars*: the margin offset ``dv`` depends on the rows'
+  distance regions, which differ per bank, so the comparator threshold
+  is applied per bank slice with the one-bank scalar expression: no
+  array promotion drift.
+* *Row slots*: the banks' row maps must agree on which slot a row
+  occupies (:class:`FusedExecutionError` otherwise), so multi-bank ISA
+  ops recycle row slots on entry, pinning all banks to one first-touch
+  order.  That is parity-neutral: one-bank callers recycle at least that
+  often, recycling logs and draws nothing, and every op re-stages the
+  rows it reads under ``track_unshared=False``.
+
+Banks must run the *same command sequence with the same activation
+geometry* (row counts per APA, :class:`FusedGeometryError` otherwise);
+their data, noise, offsets, regions and decoder row sets may differ.
+The one place the code branches on the bank count is the resolve kernel's
+call form: at one bank it gets the ``(w,)`` static offsets and the scalar
+threshold shift; over N banks each bank's shift is folded into a
+per-trial ``(N*T, w)`` static plane (one re-associated float add, so
+multi-bank parity on the Pallas backend is tolerance-class like
+Pallas-vs-numpy, while the numpy backend is bit-exact).
+
 Resolve backends
 ----------------
 The sense-amp comparator of the Boolean-op protocol (``_resolve``) is
@@ -103,8 +141,98 @@ _UNIFORM_BITS = {np.dtype(np.float32): (24, 32),
                  np.dtype(np.float64): (53, 64)}
 
 
-def _uniform_hits(rng: np.random.Generator, size: int, dtype,
-                  p: float) -> np.ndarray:
+class CapabilityError(RuntimeError):
+    """The module cannot express the requested activation/op."""
+
+
+class FusedExecutionError(RuntimeError):
+    """Per-bank execution diverged where the bank axis requires lockstep
+    (row-slot allocation or noise-context sign) — a bug guard, not a
+    capability limit: callers should gate fusion, not catch this."""
+
+
+class FusedGeometryError(CapabilityError):
+    """Banks disagree on activation geometry (row counts / fan-in), so
+    the command sequence cannot run as one pass over the bank axis.
+    Callers fall back to one sim per bank."""
+
+
+class PerBank:
+    """Marker wrapper for per-bank values on a multi-bank sim's APIs.
+
+    Wraps an ``(N, ...)`` integer array (leading axis = banks).  BankSim
+    methods receiving a plain row/int broadcast it to all banks; a
+    ``PerBank`` carries bank-distinct rows (decoder row sets differ per
+    bank seed).  Indexing selects positions along each bank's row list.
+    """
+
+    __slots__ = ("vals",)
+
+    def __init__(self, vals):
+        self.vals = np.asarray(vals, dtype=np.int64)
+
+    def __getitem__(self, k) -> "PerBank":
+        return PerBank(self.vals[:, k])
+
+    def __repr__(self) -> str:  # pragma: no cover - debug aid
+        return f"PerBank({self.vals.tolist()})"
+
+
+@dataclass(frozen=True, slots=True)
+class FusedActivation:
+    """Per-bank activation sets of one APA over the bank axis (uniform
+    geometry); ``rows_f`` / ``rows_l`` are ``PerBank`` ``(N, n)``."""
+
+    n_rf: int
+    n_rl: int
+    kind: str
+    rows_f: PerBank
+    rows_l: PerBank
+
+    @classmethod
+    def of(cls, acts: list) -> "FusedActivation":
+        """Stack each bank's decoder Activation; the banks must agree on
+        the row counts."""
+        a0 = acts[0]
+        if any(a.n_rf != a0.n_rf or a.n_rl != a0.n_rl for a in acts[1:]):
+            raise FusedGeometryError(
+                "activation geometry differs across banks: "
+                f"{[(a.n_rf, a.n_rl) for a in acts]}")
+        return cls(a0.n_rf, a0.n_rl, a0.kind,
+                   PerBank(np.asarray([a.rows_f for a in acts],
+                                      dtype=np.int64)),
+                   PerBank(np.asarray([a.rows_l for a in acts],
+                                      dtype=np.int64)))
+
+
+class _BankRng:
+    """One per-command generator per bank; draws concatenate bank-major,
+    so slice ``[b*T:(b+1)*T]`` of every draw is bank b's own draw."""
+
+    __slots__ = ("gens", "t")
+
+    def __init__(self, gens: list, t: int):
+        self.gens = gens
+        self.t = t
+
+    def _draw(self, name: str, shape, dtype) -> np.ndarray:
+        shape = tuple(shape)
+        if shape[0] != self.t * len(self.gens):
+            raise FusedExecutionError(
+                f"fused draw of shape {shape} does not stack "
+                f"{len(self.gens)} banks x {self.t} trials")
+        return np.concatenate([getattr(g, name)((self.t, *shape[1:]),
+                                                dtype=dtype)
+                               for g in self.gens])
+
+    def standard_normal(self, shape, dtype=np.float64) -> np.ndarray:
+        return self._draw("standard_normal", shape, dtype)
+
+    def random(self, shape, dtype=np.float64) -> np.ndarray:
+        return self._draw("random", shape, dtype)
+
+
+def _uniform_hits(rng, size: int, dtype, p: float) -> np.ndarray:
     """Flat indices where ``rng.random(size, dtype) < p``, bit for bit,
     read off the generator's raw words without making the floats.
 
@@ -113,7 +241,12 @@ def _uniform_hits(rng: np.random.Generator, size: int, dtype,
     float64 as ``(u64 >> 11) * 2**-53``.  So ``u < p`` holds exactly when
     ``word < ceil(p * 2**m) << (bits - m)``, with ``p`` cast as the float
     comparison casts it (a Python float to ``dtype``; a NumPy float64
-    promotes the comparison to float64)."""
+    promotes the comparison to float64).  A :class:`_BankRng` reads each
+    bank's slice off that bank's generator."""
+    if isinstance(rng, _BankRng):
+        n = size // len(rng.gens)
+        return np.concatenate([_uniform_hits(g, n, dtype, p) + b * n
+                               for b, g in enumerate(rng.gens)])
     m, bits = _UNIFORM_BITS[np.dtype(dtype)]
     pc = float(np.asarray(p, dtype=np.result_type(dtype, p)))
     k = math.ceil(min(pc, 1.0) * 2.0 ** m)
@@ -223,7 +356,8 @@ class CommandLog:
 
 
 class BankSim:
-    """One DRAM bank: lazily-allocated subarrays of float32 cell voltages."""
+    """One DRAM bank — or N stacked on the trial axis (module doc, *Bank
+    axis*): lazily-allocated subarrays of float32 cell voltages."""
 
     def __init__(self, module: ModuleConfig | str | None = None, *,
                  row_bits: int | None = None, seed: int = 0,
@@ -247,10 +381,8 @@ class BankSim:
         self.error_model = error_model
         self.seed = seed
         #: bank index stamped on every CommandLog event (array position;
-        #: purely log metadata — the sim itself is always one bank)
+        #: purely log metadata)
         self.bank = int(bank)
-        #: independent per-trial noise stream (chip identity stays ``seed``)
-        self.noise_seed = seed if noise_seed is None else int(noise_seed)
         if resolve_backend not in ("auto", "numpy", "pallas"):
             raise ValueError(f"unknown resolve backend {resolve_backend!r}")
         self.resolve_backend = resolve_backend
@@ -273,10 +405,16 @@ class BankSim:
         #: snapshots must be cell-accurate.
         self.track_unshared = track_unshared
         self._subarrays: dict[int, np.ndarray] = {}
+        #: per subarray, the bank-major row -> slot map (bank b's row r
+        #: at ``b * rows_per_subarray + r``; -1 = no slot yet)
         self._rowmap: dict[int, np.ndarray] = {}
         self._nrows: dict[int, int] = {}
         self._static: dict[int, tuple[np.ndarray, np.ndarray]] = {}
-        self._trial = 0
+        #: memoized resolve scalars and NOT success planes: pure functions
+        #: of chip identity and the op context
+        self._param_cache: dict = {}
+        self._not_z_cache: dict = {}
+        self._set_banks([seed], [seed if noise_seed is None else noise_seed])
         # stripe-major internal column layout: storage position j < w holds
         # physical column 2j+1 (the lower-stripe shared set), position w+j
         # holds column 2j.  Shared-column access — the hot path — is then a
@@ -298,6 +436,45 @@ class BankSim:
     def batched(self) -> bool:
         return self.trials is not None
 
+    # ---------------- the bank axis ----------------
+    def _set_banks(self, seeds, noise_seeds) -> None:
+        """Lay ``len(seeds)`` banks over the trial axis: chip identities
+        ``seeds``, noise streams ``noise_seeds``, fresh command counters."""
+        #: each bank's chip identity (decoder map + static SA offsets)
+        self.bank_seeds = [int(s) for s in seeds]
+        self.n_banks = len(self.bank_seeds)
+        self.trials_per_bank = self._T // self.n_banks
+        self._bank_base = (np.arange(self.n_banks, dtype=np.int64)[:, None]
+                           * self.geom.rows_per_subarray)
+        #: each bank's per-trial noise stream (chip identity stays put)
+        self.bank_noise_seeds = [int(s) for s in noise_seeds]
+        #: each bank's command counter (one generator per command)
+        self._bank_trial = [0] * self.n_banks
+
+    @property
+    def _trial(self) -> int:
+        """Bank 0's command counter."""
+        return self._bank_trial[0]
+
+    def _bank_slices(self) -> list[slice]:
+        """Each bank's rows of the stacked trial axis."""
+        t = self.trials_per_bank
+        return [slice(b * t, (b + 1) * t) for b in range(self.n_banks)]
+
+    def _pb_vals(self, rows) -> np.ndarray:
+        """(N, k) per-bank row matrix from a PerBank or a shared spec."""
+        if isinstance(rows, PerBank):
+            r = rows.vals
+            if r.ndim == 1:
+                r = r[:, None]
+            if r.ndim != 2 or r.shape[0] != self.n_banks:
+                raise ValueError(
+                    f"PerBank rows must be ({self.n_banks}, k), got shape "
+                    f"{rows.vals.shape}")
+            return r
+        base = np.atleast_1d(np.asarray(rows, dtype=np.int64))
+        return np.broadcast_to(base, (self.n_banks, base.size))
+
     # ---------------- compact row-remapped cell storage ----------------
     # Physical row addresses map to densely-allocated slots of a
     # (T, slots, row_bits) buffer per subarray: a bank exposes 512 rows but
@@ -305,25 +482,35 @@ class BankSim:
     # trial-batched gathers/scatters contiguous instead of striding a
     # (T, 512, row_bits) arena.  Unwritten rows read as 0 V (cold cells).
     def _map_rows(self, sub: int, rows) -> np.ndarray:
-        """Slot indices of physical rows, allocating slots on first touch."""
+        """Slot indices of physical rows, allocating slots on first touch.
+
+        ``rows`` is shared by every bank or a :class:`PerBank` matrix; the
+        banks allocate in lockstep and must agree on every slot."""
         if not 0 <= sub < self.geom.subarrays_per_bank:
             raise IndexError(f"subarray {sub} out of range")
-        rows = np.atleast_1d(np.asarray(rows, dtype=np.int64))
-        if rows.size and (rows.min() < 0
-                          or rows.max() >= self.geom.rows_per_subarray):
-            raise IndexError(f"row out of range in {rows}")
+        r = self._pb_vals(rows)
+        if r.size and (r.min() < 0
+                       or r.max() >= self.geom.rows_per_subarray):
+            raise IndexError(f"row out of range in {r}")
         rmap = self._rowmap.get(sub)
         if rmap is None:
             rmap = self._rowmap[sub] = np.full(
-                self.geom.rows_per_subarray, -1, dtype=np.int64)
+                self.n_banks * self.geom.rows_per_subarray, -1,
+                dtype=np.int64)
             self._nrows[sub] = 0
-        idx = rmap[rows]
+        flat = r + self._bank_base
+        idx = rmap[flat]
         fresh = idx < 0
         if np.any(fresh):
-            new_rows = rows[fresh]
+            if not (fresh == fresh[0]).all():
+                raise FusedExecutionError(
+                    "per-bank first-touch order diverged (some banks have "
+                    "already allocated a row others have not) — fused ops "
+                    "must recycle rows so all banks allocate in lockstep")
+            new_rows = flat[:, fresh[0]]
             start = self._nrows[sub]
-            rmap[new_rows] = np.arange(start, start + new_rows.size)
-            self._nrows[sub] = start + new_rows.size
+            rmap[new_rows] = np.arange(start, start + new_rows.shape[1])
+            self._nrows[sub] = start + new_rows.shape[1]
             buf = self._subarrays.get(sub)
             cap = 0 if buf is None else buf.shape[1]
             if self._nrows[sub] > cap:
@@ -334,8 +521,12 @@ class BankSim:
                 if buf is not None:
                     new_buf[:, :cap] = buf
                 self._subarrays[sub] = new_buf
-            idx = rmap[rows]
-        return idx
+            idx = rmap[flat]
+        if idx.size and not (idx == idx[0]).all():
+            raise FusedExecutionError(
+                "per-bank slot maps diverged — banks disagree on which "
+                "storage slot a row occupies")
+        return idx[0]
 
     def _row(self, sub: int, row: int) -> int:
         return int(self._map_rows(sub, row)[0])
@@ -374,29 +565,51 @@ class BankSim:
         return rows if self.batched else rows[0]
 
     def _static_latents(self, stripe: int) -> tuple[np.ndarray, np.ndarray]:
-        """Two per-SA uniforms for the static offset mixture of a stripe."""
+        """Two per-SA uniforms for the static offset mixture of a stripe:
+        ``(w,)`` each at one bank, each bank's stacked ``(N, w)`` over N."""
         if stripe not in self._static:
-            rng = np.random.default_rng(
-                np.random.SeedSequence([self.seed, 0xC0FFEE, stripe]))
-            self._static[stripe] = (rng.random(self.shared_w),
-                                    rng.random(self.shared_w))
+            lat = []
+            for s in self.bank_seeds:
+                rng = np.random.default_rng(
+                    np.random.SeedSequence([s, 0xC0FFEE, stripe]))
+                lat.append((rng.random(self.shared_w),
+                            rng.random(self.shared_w)))
+            self._static[stripe] = lat[0] if self.n_banks == 1 else \
+                tuple(np.stack(x) for x in zip(*lat, strict=True))
         return self._static[stripe]
 
-    def _rng(self) -> np.random.Generator:
-        self._trial += 1
-        return np.random.default_rng(
-            np.random.SeedSequence([self.noise_seed, 0x7A1A1, self._trial]))
+    def _rng(self):
+        """This command's generator: a plain one at one bank, else one per
+        bank (:class:`_BankRng`)."""
+        gens = []
+        for b, s in enumerate(self.bank_noise_seeds):
+            self._bank_trial[b] += 1
+            gens.append(np.random.default_rng(
+                np.random.SeedSequence([s, 0x7A1A1, self._bank_trial[b]])))
+        return gens[0] if self.n_banks == 1 else \
+            _BankRng(gens, self.trials_per_bank)
 
-    def reseed_noise(self, noise_seed: int) -> None:
+    def reseed_noise(self, noise_seed) -> None:
         """Point subsequent per-trial noise draws at an independent stream.
 
         Chip identity — the decoder's activation map and the static per-SA
         offsets — stays tied to ``seed``; only the per-command noise/floor
         generators change.  The command counter restarts so the stream is a
         pure function of ``noise_seed`` (callers pass unique seeds, e.g.
-        ``np.random.SeedSequence(seed).spawn`` children)."""
-        self.noise_seed = int(noise_seed)
-        self._trial = 0
+        ``np.random.SeedSequence(seed).spawn`` children).  A sim over N
+        banks takes one seed per bank (an int only at one bank)."""
+        if isinstance(noise_seed, (int, np.integer)):
+            if self.n_banks != 1:
+                raise ValueError(
+                    f"fused sim over {self.n_banks} banks needs one noise "
+                    "seed per bank (a shared seed would collide streams)")
+            noise_seed = [noise_seed]
+        seeds = [int(s) for s in noise_seed]
+        if len(seeds) != self.n_banks:
+            raise ValueError(f"need {self.n_banks} noise seeds, got "
+                             f"{len(seeds)}")
+        self.bank_noise_seeds = seeds
+        self._bank_trial = [0] * self.n_banks
 
     def _resolve_backend(self) -> str:
         """Effective resolve backend ('auto' settles on first use)."""
@@ -432,16 +645,11 @@ class BankSim:
                 f"axis), got {bits.shape}")
         i = self._row(sub, row)
         self._cells(sub)[:, i] = bits[..., self._perm].astype(np.float32)
-        t = self.timings
-        n_bursts = self.geom.row_bits // 512  # 64B bursts per chip-row
-        self.log.add("WR", t.tRCD + t.tWR + t.tRP,
-                     ENERGY_PJ["act"] + ENERGY_PJ["pre"]
-                     + n_bursts * ENERGY_PJ["wr_per_64B"],
-                     bank=self.bank, sub=sub)
+        self._log_wr(sub=sub)
 
     def _log_wr(self, n_rows: int = 1, sub: int = -1) -> None:
         t = self.timings
-        n_bursts = self.geom.row_bits // 512
+        n_bursts = self.geom.row_bits // 512  # 64B bursts per chip-row
         self.log.add("WR", t.tRCD + t.tWR + t.tRP,
                      ENERGY_PJ["act"] + ENERGY_PJ["pre"]
                      + n_bursts * ENERGY_PJ["wr_per_64B"], count=n_rows,
@@ -473,15 +681,18 @@ class BankSim:
             self._cells(sub)[:, idx] = value
         self._log_wr(len(idx), sub=sub)
 
-    def read_row(self, sub: int, row: int) -> np.ndarray:
-        i = self._row(sub, row)
-        arr = self._cells(sub)
+    def _log_rd(self, sub: int) -> None:
         t = self.timings
         n_bursts = self.geom.row_bits // 512
         self.log.add("RD", t.tRCD + t.tCL + t.tRP,
                      ENERGY_PJ["act"] + ENERGY_PJ["pre"]
                      + n_bursts * ENERGY_PJ["rd_per_64B"],
                      bank=self.bank, sub=sub)
+
+    def read_row(self, sub: int, row: int) -> np.ndarray:
+        i = self._row(sub, row)
+        arr = self._cells(sub)
+        self._log_rd(sub)
         return self._out((arr[:, i][..., self._invperm] > 0.5)
                          .astype(np.uint8))
 
@@ -497,7 +708,7 @@ class BankSim:
                      2 * (ENERGY_PJ["act"] + ENERGY_PJ["pre"]),
                      bank=self.bank, sub=sub)
 
-    def rowclone(self, sub: int, src: int, dst: int) -> None:
+    def rowclone(self, sub: int, src, dst) -> None:
         """Same-subarray RowClone (sequential ACT -> PRE -> ACT).
 
         Trial-batched like every other command (the copy broadcasts over
@@ -511,13 +722,17 @@ class BankSim:
         """
         rmap = self._rowmap.get(sub)
         nrows = self.geom.rows_per_subarray
-        if (rmap is not None and isinstance(src, (int, np.integer))
+        if (self.n_banks == 1 and rmap is not None
+                and isinstance(src, (int, np.integer))
                 and isinstance(dst, (int, np.integer))
                 and 0 <= src < nrows and 0 <= dst < nrows
                 and rmap[src] >= 0 and rmap[dst] >= 0):
+            # one bank's row map has no bank axis: read both slots off it
             isrc, idst = rmap[src], rmap[dst]
         else:
-            isrc, idst = self._map_rows(sub, [src, dst])
+            isrc, idst = self._map_rows(sub, PerBank(np.concatenate(
+                [self._pb_vals(src)[:, :1], self._pb_vals(dst)[:, :1]],
+                axis=1)))
         arr = self._cells(sub)
         restored = arr[:, isrc]
         np.greater(restored, 0.5, out=restored)  # source restored
@@ -568,33 +783,46 @@ class BankSim:
         return slice(w, 2 * w) if sl.start == 0 else slice(0, w)
 
     def _resolve_params(self, stripe: int, op: str, n: int, *,
-                        regions: tuple[int, int], random_pattern: bool):
+                        regions, random_pattern: bool):
         """Shared analog-model scalars of one comparator resolve:
-        (margin offset dv, noise sigma s, threshold shift, static offsets,
-        activation-failure floor pf)."""
-        p = self.params
-        dv = A.margin_offset(op, p, compute_region=regions[0],
-                             ref_region=regions[1],
-                             mfr=self.module.manufacturer.value,
-                             density_gb=self.module.density_gb,
-                             die_rev=self.module.die_rev)
-        s, _b, _wp, _wm = A.op_noise(
-            op, n, p, temp_c=self.temp_c, random_pattern=random_pattern,
-            speed_mts=self.module.speed_mts,
-            mfr=self.module.manufacturer.value,
-            density_gb=self.module.density_gb, die_rev=self.module.die_rev)
-        shift = A.op_shift(op, n, p)
-        static = self.static_offsets(stripe, op, n,
-                                     random_pattern=random_pattern) \
-            .astype(self._noise_dtype, copy=False)
-        pf = A.op_pfloor(op, n, p, temp_c=self.temp_c,
-                         random_pattern=random_pattern,
-                         speed_mts=self.module.speed_mts)
-        return dv, s, shift, static, pf
+        (each bank's margin offset dv, noise sigma s, threshold shift,
+        static offsets, activation-failure floor pf).  ``regions`` are the
+        compute and reference rows' distance regions, one per bank (the
+        margin offset depends on them); the static offsets are ``(w,)`` at
+        one bank, else a per-trial ``(N*T, w)`` plane.  Memoized — every
+        value is a pure function of chip identity and the op context."""
+        reg_c = tuple(int(x) for x in np.atleast_1d(regions[0]))
+        reg_r = tuple(int(x) for x in np.atleast_1d(regions[1]))
+        key = (stripe, op, n, random_pattern, reg_c, reg_r)
+        cached = self._param_cache.get(key)
+        if cached is None:
+            p = self.params
+            dv = tuple(
+                A.margin_offset(op, p, compute_region=c, ref_region=r,
+                                mfr=self.module.manufacturer.value,
+                                density_gb=self.module.density_gb,
+                                die_rev=self.module.die_rev)
+                for c, r in zip(reg_c, reg_r, strict=True))
+            s, _b, _wp, _wm = A.op_noise(
+                op, n, p, temp_c=self.temp_c, random_pattern=random_pattern,
+                speed_mts=self.module.speed_mts,
+                mfr=self.module.manufacturer.value,
+                density_gb=self.module.density_gb,
+                die_rev=self.module.die_rev)
+            shift = A.op_shift(op, n, p)
+            static = self.static_offsets(stripe, op, n,
+                                         random_pattern=random_pattern) \
+                .astype(self._noise_dtype, copy=False)
+            if self.n_banks > 1:        # (N, w) -> each bank's trials
+                static = np.repeat(static, self.trials_per_bank, axis=0)
+            pf = A.op_pfloor(op, n, p, temp_c=self.temp_c,
+                             random_pattern=random_pattern,
+                             speed_mts=self.module.speed_mts)
+            cached = self._param_cache[key] = (dv, s, shift, static, pf)
+        return cached
 
     def _resolve(self, margin: np.ndarray, stripe: int, op: str, n: int, *,
-                 regions: tuple[int, int], random_pattern: bool,
-                 rng: np.random.Generator) -> np.ndarray:
+                 regions, random_pattern: bool, rng) -> np.ndarray:
         """Sense-amp comparator outcome (bool per (trial, shared column)).
 
         ``margin`` is (T, w); static offsets broadcast across trials (one
@@ -611,7 +839,10 @@ class BankSim:
         acc *= math.sqrt(max(1.0 - STATIC_SPLIT ** 2, 0.0)) * s
         acc += margin
         acc += static
-        out = acc > -(dv - shift - p.delta_v)
+        # each bank's threshold, a scalar expression per bank slice
+        out = np.empty(margin.shape, dtype=bool)
+        for sl, dv_b in zip(self._bank_slices(), dv, strict=True):
+            np.greater(acc[sl], -(dv_b - shift - p.delta_v), out=out[sl])
         if self.batched:
             # one uniform: conditioned on u < pf, (u < pf/2) is a fair coin
             u = rng.random(margin.shape, dtype=self._noise_dtype)
@@ -622,9 +853,8 @@ class BankSim:
 
     def _resolve_pallas(self, com_cells: np.ndarray, ref_cells: np.ndarray,
                         u_com: float, u_ref: float, stripe: int, op: str,
-                        n: int, *, regions: tuple[int, int],
-                        random_pattern: bool,
-                        rng: np.random.Generator) -> np.ndarray:
+                        n: int, *, regions, random_pattern: bool,
+                        rng) -> np.ndarray:
         """Fused charge-share + sense-amp resolve through the Pallas kernel.
 
         ``com_cells`` / ``ref_cells`` are the activated cell slabs
@@ -652,13 +882,47 @@ class BankSim:
             trial_sigma = math.sqrt(max(1.0 - STATIC_SPLIT ** 2, 0.0)) * s
             static = static.astype(np.float32, copy=False)
             nz = nz.astype(np.float32, copy=False)
-        # numpy threshold: margin + static + noise > -(dv - shift - delta_v)
-        # kernel threshold: margin_k - shift_k + static + noise > 0
+            # numpy threshold: margin + static + noise > -(dv - shift - delta_v)
+            # kernel threshold: margin_k - shift_k + static + noise > 0
+            if self.n_banks == 1:
+                shift_k = float(shift + p.delta_v - dv[0])
+            else:
+                # each bank's shift folds into the per-trial static plane
+                shift_col = np.repeat(
+                    np.asarray([shift + p.delta_v - dv_b for dv_b in dv],
+                               dtype=np.float32), self.trials_per_bank)
+                static, shift_k = static - shift_col[:, None], 0.0
         return _resolve_call(
             com_cells, ref_cells, static, nz, un,
-            u_com=float(u_com), u_ref=float(u_ref),
-            shift=float(shift + p.delta_v - dv), pf=float(pf),
-            trial_sigma=float(trial_sigma))
+            u_com=float(u_com), u_ref=float(u_ref), shift=shift_k,
+            pf=float(pf), trial_sigma=float(trial_sigma))
+
+    def _not_plane(self, b: int, stripe: int, act, reg_f: int,
+                   reg_l: int) -> np.ndarray:
+        """Bank b's per-cell NOT success probability ``(w,)`` (memoized).
+
+        Static per-cell variation around the mean success rate;
+        E[phi(a + s Z)] = phi(a / sqrt(1+s^2)) keeps the cell-mean exactly
+        equal to the closed-form not_success."""
+        key = (b, stripe, act.n_rl, act.kind, int(reg_f), int(reg_l))
+        z = self._not_z_cache.get(key)
+        if z is None:
+            p_ok = A.not_success(
+                act.n_rl, pattern=("N2N" if act.kind == "N:2N" else "NN"),
+                p=self.params, temp_c=self.temp_c,
+                src_region=int(reg_f), dst_region=int(reg_l),
+                speed_mts=self.module.speed_mts,
+                mfr=self.module.manufacturer.value,
+                density_gb=self.module.density_gb,
+                die_rev=self.module.die_rev)
+            spread = 0.75
+            xi1 = np.atleast_2d(self._static_latents(stripe)[0])[b]
+            a = _norm_ppf(np.clip(p_ok, 1e-9, 1 - 1e-9)) \
+                * math.sqrt(1.0 + spread ** 2)
+            z = self._not_z_cache[key] = \
+                A.phi(a + spread * _norm_ppf(xi1)) \
+                .astype(self._noise_dtype, copy=False)
+        return z
 
     def _maj_restore(self, sub: int, rows, cols: slice,
                      rng: np.random.Generator) -> None:
@@ -677,67 +941,65 @@ class BankSim:
         arr[:, rows, cols] = out[:, None, :]
 
     @tracing.traced("sim.apa")
-    def apa(self, rf_global: int, rl_global: int, *,
-            first_act_restored: bool = False,
-            random_pattern: bool = True) -> DEC.Activation:
+    def apa(self, rf_global, rl_global, *, first_act_restored: bool = False,
+            random_pattern: bool = True):
         """``ACT R_F -> PRE -> ACT R_L`` with violated timings.
 
-        Global row address = subarray * rows_per_subarray + row.
-        ``first_act_restored=True`` models the NOT protocol (§5): the first
-        ACT waits full tRAS, so R_F's value is fully restored and then
-        *drives* the R_L rows through the shared SAs.  Otherwise both sides
-        charge-share from VDD/2 and the SA acts as a comparator (§6).
+        Global row address = subarray * rows_per_subarray + row; a
+        :class:`PerBank` address gives each bank its own rows in one
+        subarray pair.  ``first_act_restored=True`` models the NOT protocol
+        (§5): the first ACT waits full tRAS, so R_F's value is fully
+        restored and then *drives* the R_L rows through the shared SAs.
+        Otherwise both sides charge-share from VDD/2 and the SA acts as a
+        comparator (§6).  Returns the decoder's Activation (over N banks,
+        a :class:`FusedActivation` of every bank's rows).
         """
         rps = self.geom.rows_per_subarray
-        f_sub, f_row = divmod(rf_global, rps)
-        l_sub, l_row = divmod(rl_global, rps)
-        act = DEC.activation_pattern(self.module, f_row, l_row, seed=self.seed)
+        f_subs, f_rows = np.divmod(self._pb_vals(rf_global)[:, 0], rps)
+        l_subs, l_rows = np.divmod(self._pb_vals(rl_global)[:, 0], rps)
+        if (f_subs != f_subs[0]).any() or (l_subs != l_subs[0]).any():
+            raise FusedGeometryError(
+                "fused APA needs one subarray pair shared by all banks")
+        f_sub, l_sub = int(f_subs[0]), int(l_subs[0])
+        acts = [DEC.activation_pattern(self.module, int(f), int(l), seed=s)
+                for f, l, s in zip(f_rows, l_rows, self.bank_seeds,
+                                   strict=True)]
+        fact = FusedActivation.of(acts)
+        act = acts[0] if self.n_banks == 1 else fact
         t = self.timings
         t_first = t.tRAS if first_act_restored else VIOLATED_TRAS_NS
         self.log.add("APA", t_first + VIOLATED_TRP_NS + t.tRAS + t.tRP,
-                     (act.n_rf + act.n_rl) * ENERGY_PJ["act"]
+                     (fact.n_rf + fact.n_rl) * ENERGY_PJ["act"]
                      + 2 * ENERGY_PJ["pre"],
                      bank=self.bank, sub=f_sub)
-        if act.n_rf == 0:
+        if fact.n_rf == 0:
             return act
         if self.module.activation is ActivationSupport.SEQUENTIAL \
                 and not first_act_restored:
             return act  # sequential activation cannot charge-share both sides
         stripe, f_cols, l_cols = self._col_slices(f_sub, l_sub)
-        rows_f = self._map_rows(f_sub, act.rows_f)
-        rows_l = self._map_rows(l_sub, act.rows_l)
+        rows_f = self._map_rows(f_sub, fact.rows_f)
+        rows_l = self._map_rows(l_sub, fact.rows_l)
         arr_f, arr_l = self._cells(f_sub), self._cells(l_sub)
         rng = self._rng()
         geom = self.geom
-        reg_f = geom.distance_region(f_row, toward_upper=f_sub > l_sub)
-        reg_l = geom.distance_region(l_row, toward_upper=l_sub > f_sub)
+        reg_f = geom.distance_regions(f_rows, toward_upper=f_sub > l_sub)
+        reg_l = geom.distance_regions(l_rows, toward_upper=l_sub > f_sub)
 
         if first_act_restored:
             # ---- NOT protocol: R_F drives, R_L receives the complement ----
-            n_src = act.n_rf
+            n_src = fact.n_rf
             u = A.u_n(n_src, self.params)
             v_src = 0.5 + u * (np.sum(arr_f[:, rows_f, f_cols], axis=1)
                                - 0.5 * n_src)
             src_bit = v_src > 0.5                       # (T, w)
             if self.error_model == "analog":
-                p_ok = A.not_success(
-                    act.n_rl, pattern=("N2N" if act.kind == "N:2N" else "NN"),
-                    p=self.params, temp_c=self.temp_c,
-                    src_region=reg_f, dst_region=reg_l,
-                    speed_mts=self.module.speed_mts,
-                    mfr=self.module.manufacturer.value,
-                    density_gb=self.module.density_gb,
-                    die_rev=self.module.die_rev)
-                # static per-cell variation around the mean success rate;
-                # E[phi(a + s Z)] = phi(a / sqrt(1+s^2)) keeps the cell-mean
-                # exactly equal to the closed-form not_success.
-                spread = 0.75
-                xi1, _xi2 = self._static_latents(stripe)
-                a = _norm_ppf(np.clip(p_ok, 1e-9, 1 - 1e-9)) \
-                    * math.sqrt(1.0 + spread ** 2)
-                z = A.phi(a + spread * _norm_ppf(xi1)) \
-                    .astype(self._noise_dtype, copy=False)  # (w,) per-cell
-                ok = rng.random(src_bit.shape, dtype=self._noise_dtype) < z
+                draws = rng.random(src_bit.shape, dtype=self._noise_dtype)
+                ok = np.empty(src_bit.shape, dtype=bool)
+                for b, sl in enumerate(self._bank_slices()):
+                    np.less(draws[sl],
+                            self._not_plane(b, stripe, fact, reg_f[b],
+                                            reg_l[b]), out=ok[sl])
             else:
                 ok = np.ones(src_bit.shape, dtype=bool)
             dst_bit = np.where(ok, ~src_bit, src_bit).astype(np.float32)
@@ -746,14 +1008,20 @@ class BankSim:
             arr_f[:, rows_f, f_cols] = src_f[:, None, :]
         else:
             # ---- Boolean-op protocol: comparator across the stripe ----
-            n_f, n_l = act.n_rf, act.n_rl
+            n_f, n_l = fact.n_rf, fact.n_rl
             u_f = A.u_n(n_f, self.params)
             u_l = A.u_n(n_l, self.params)
             v_f = u_f * (np.sum(arr_f[:, rows_f, f_cols], axis=1)
                          - 0.5 * n_f)
             # noise context: the reference level sets the common mode
-            # (V_REF > VDD/2 -> AND-family, < VDD/2 -> OR-family)
-            op_ctx = "and" if float(np.mean(v_f)) >= 0.0 else "or"
+            # (V_REF > VDD/2 -> AND-family, < VDD/2 -> OR-family); every
+            # bank runs the same op with same-sign references
+            ctx = {float(np.mean(v_f[sl])) >= 0.0
+                   for sl in self._bank_slices()}
+            if len(ctx) != 1:
+                raise FusedExecutionError(
+                    "reference common-mode sign differs across banks")
+            op_ctx = "and" if ctx.pop() else "or"
             if self.error_model == "analog" \
                     and self._resolve_backend() == "pallas":
                 out = self._resolve_pallas(
@@ -823,7 +1091,9 @@ class BankSim:
                         random_pattern=random_pattern)
 
     # ---------------- convenience ----------------
-    def global_addr(self, sub: int, row: int) -> int:
+    def global_addr(self, sub: int, row):
+        if isinstance(row, PerBank):
+            return PerBank(sub * self.geom.rows_per_subarray + row.vals)
         return sub * self.geom.rows_per_subarray + row
 
     def read_shared_word(self, sub: int, row: int, sl: slice) -> np.ndarray:
@@ -831,12 +1101,7 @@ class BankSim:
         the ISA's result readout ((w,), or (T, w) batched).  Logged as a
         full RD: the host pulls the row over the DDR bus to get the word."""
         i = self._row(sub, row)
-        t = self.timings
-        n_bursts = self.geom.row_bits // 512
-        self.log.add("RD", t.tRCD + t.tCL + t.tRP,
-                     ENERGY_PJ["act"] + ENERGY_PJ["pre"]
-                     + n_bursts * ENERGY_PJ["rd_per_64B"],
-                     bank=self.bank, sub=sub)
+        self._log_rd(sub)
         return self._out((self._cells(sub)[:, i, sl] > 0.5).astype(np.uint8))
 
     def snapshot_rows(self, sub: int, rows) -> np.ndarray:

@@ -31,9 +31,10 @@ import time
 SPAN_NAMES = (
     # MC harness (core/charz.py)
     "charz.estimate", "charz.chip", "charz.draw", "charz.op", "charz.count",
-    # ISA (core/isa.py, core/fused.py)
+    # ISA (core/isa.py, at every bank count)
     "isa.stage", "isa.readout", "isa.inventory",
-    # simulator and the resolve boundary (core/simulator.py, core/fused.py)
+    # simulator and the resolve boundary (core/simulator.py, at every bank
+    # count)
     "sim.apa", "sim.resolve_prep", "sim.resolve_call",
     # planner and resident executor (core/compiler.py, core/isa.py)
     "compiler.schedule", "resident.exec", "resident.rowclone",

@@ -20,19 +20,27 @@ The load-bearing guarantees of ``repro.core.fused`` and its dispatchers:
   continuity across calls,
 * **dealers** — round-robin stays the reproducible default;
   the occupancy dealer balances uneven loads (never a worse makespan)
-  and rejects unknown dealers / malformed weights.
+  and rejects unknown dealers / malformed weights,
+* **kernel call form** — the Pallas resolve entry gets the ``(w,)``
+  static offsets and the scalar threshold shift at one bank, a per-trial
+  ``(N*T, w)`` plane and a zero shift over N banks, and the same kernel
+  scalars at both; the resolve scalars are memoized.
 """
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.core import analog as A
 from repro.core import charz
 from repro.core import compiler as CC
+from repro.core import simulator
 from repro.core.bankarray import BankArray
 from repro.core.fused import (FusedBankSim, FusedGeometryError, FusedPudIsa,
                               PerBank)
+from repro.core.isa import PudIsa
 from repro.core.policy import EngineConfig, ResidentPolicy
 from repro.core.simulator import BankSim
+from repro.kernels import ref as kref
 
 
 def _loop_episode(arr, ops_by_bank, not_bits_by_bank):
@@ -54,7 +62,7 @@ def _loop_episode(arr, ops_by_bank, not_bits_by_bank):
 # property: fused == loop, results and command logs
 # ---------------------------------------------------------------------------
 @settings(max_examples=8, deadline=None)
-@given(banks=st.integers(min_value=2, max_value=4),
+@given(banks=st.integers(min_value=1, max_value=4),
        trials=st.integers(min_value=1, max_value=3),
        row_bits=st.sampled_from([128, 256]))
 def test_fused_matches_loop_bitwise(banks, trials, row_bits):
@@ -296,3 +304,93 @@ def test_absorb_state_roundtrip():
     assert wide._bank_cursors[1][(2, 1)] == 9
     with pytest.raises(ValueError, match="narrower"):
         narrow.absorb_state(wide)
+
+
+# ---------------------------------------------------------------------------
+# the resolve kernel's call form (what the chip compiles and counts)
+# ---------------------------------------------------------------------------
+def _resolve_calls(monkeypatch, banks, op, n, trials=3):
+    """One ``n``-input ``op`` on ``banks`` banks (bank 0 is chip 7) through
+    the Pallas resolve entry, the oracle standing in for the kernel.
+    -> (sim, rf, rl, n_hw, recorded (static, scalars) calls)."""
+    calls = []
+
+    def record(com, ref, static, normals, uniforms, **kw):
+        calls.append((static, kw))
+        return np.asarray(kref.senseamp_resolve_trials(
+            com, ref, static, normals, uniforms, **kw))
+
+    monkeypatch.setattr(simulator, "_resolve_call", record)
+    kw = dict(row_bits=256, error_model="analog", resolve_backend="pallas")
+    if banks == 1:
+        sim = BankSim(seed=7, trials=trials, track_unshared=False, **kw)
+        isa = PudIsa(sim)
+    else:
+        sim = FusedBankSim(bank_seeds=range(7, 7 + banks), trials=trials,
+                           **kw)
+        isa = FusedPudIsa(sim)
+    ops = np.random.default_rng(n).integers(
+        0, 2, (n, banks * trials, isa.width)).astype(np.uint8)
+    n_hw, rf, rl, act = isa.plan_nary(op, n, pair_index=5)
+    isa.exec_nary(op, rf, rl, act, ("write_stack", ops))
+    return sim, rf, rl, n_hw, calls
+
+
+@pytest.mark.parametrize("op,n", [("nand", 8), ("or", 2)])
+@pytest.mark.parametrize("banks", [1, 4])
+def test_resolve_kernel_call_form(monkeypatch, banks, op, n):
+    sim, rf, rl, n_hw, calls = _resolve_calls(monkeypatch, banks, op, n)
+    _sim1, _rf1, _rl1, _n1, calls1 = _resolve_calls(monkeypatch, 1, op, n)
+    assert len(calls) == len(calls1) == 1
+    static, kw = calls[0]
+    w, t = sim.shared_w, sim.trials_per_bank
+    ctx = A._base_op(op)[0]
+    rows_f = np.atleast_1d(getattr(rf, "vals", rf))
+    rows_l = np.atleast_1d(getattr(rl, "vals", rl))
+    reg_f = sim.geom.distance_regions(rows_f, toward_upper=False)
+    reg_l = sim.geom.distance_regions(rows_l, toward_upper=True)
+    if banks == 1:
+        p, m = sim.params, sim.module
+        dv = A.margin_offset(ctx, p, compute_region=int(reg_l[0]),
+                             ref_region=int(reg_f[0]),
+                             mfr=m.manufacturer.value,
+                             density_gb=m.density_gb, die_rev=m.die_rev)
+        assert static.shape == (w,)
+        assert kw["shift"] == A.op_shift(ctx, n_hw, p) + p.delta_v - dv
+    else:
+        assert static.shape == (banks * t, w)
+        assert kw["shift"] == 0.0
+    for k in ("u_com", "u_ref", "pf", "trial_sigma"):
+        assert kw[k] == calls1[0][1][k], k
+    # memoized: the same key returns the identical tuple
+    key = dict(regions=(reg_l, reg_f), random_pattern=True)
+    first = sim._resolve_params(0, ctx, n_hw, **key)
+    assert sim._resolve_params(0, ctx, n_hw, **key) is first
+    assert len(sim._param_cache) == 1
+
+
+@pytest.mark.parametrize("per_bank_dst", [False, True], ids=["shared", "per_bank"])
+def test_fused_rowclone_matches_per_bank(per_bank_dst):
+    """A noisy RowClone over the bank axis is each bank's own copy: the
+    same flips from each bank's generator, bit for bit."""
+    banks, trials, row_bits, p = 3, 4, 256, 0.05
+    seeds = [5, 6, 7]
+    fsim = FusedBankSim(bank_seeds=seeds, trials=trials, row_bits=row_bits,
+                        error_model="analog", rowclone_fail_p=p)
+    bits = np.random.default_rng(3).integers(
+        0, 2, (banks * trials, row_bits)).astype(np.uint8)
+    fsim.write_row(0, 1, bits)
+    dst = [2, 5, 9] if per_bank_dst else [2] * banks
+    fsim.rowclone(0, 1, PerBank(dst) if per_bank_dst else 2)
+    got = fsim.snapshot_rows(0, PerBank(dst))[:, 0]
+    assert fsim._bank_trial == [1] * banks
+    for b in range(banks):
+        sim = BankSim(seed=seeds[b], trials=trials, row_bits=row_bits,
+                      error_model="analog", rowclone_fail_p=p,
+                      track_unshared=False)
+        sl = slice(b * trials, (b + 1) * trials)
+        sim.write_row(0, 1, bits[sl])
+        sim.rowclone(0, 1, dst[b])
+        want = sim.read_row(0, dst[b])
+        assert np.array_equal(got[sl], want), f"bank {b}"
+        assert 0 < np.count_nonzero(want != bits[sl]) < want.size // 4
