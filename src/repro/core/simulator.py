@@ -42,6 +42,17 @@ and coins are drawn ``(T, w)`` at once.  With ``trials=None`` (default) the
 simulator runs a single trial and keeps the seed-compatible scalar API:
 identical RNG consumption, identical results, rows returned as 1-D arrays.
 
+An APA leaves one ``(T, w)`` bool plane in every activated row of a side,
+and the sim keeps that plane, not the rows: one pending record per
+subarray (slots, column slice, plane).  ``_cells``, the one gate to a
+subarray's slot buffer, writes the record into its rows (as 1.0 / 0.0)
+before any command reads or writes that buffer; ``read_shared_word``
+writes nothing back (a pending row's word on the pending slice is read
+off the plane, any other word off the buffer); and ``recycle_rows``
+drops every record, since recycled contents are don't-care.  An MC
+episode (recycle, stage, APA, one readout) thus never writes its APA's
+rows at all.
+
 Seed roles
 ----------
 ``seed`` is the *chip identity*: it fixes the row-decoder hash (which
@@ -410,6 +421,9 @@ class BankSim:
         self._rowmap: dict[int, np.ndarray] = {}
         self._nrows: dict[int, int] = {}
         self._static: dict[int, tuple[np.ndarray, np.ndarray]] = {}
+        #: per subarray, the last APA's result not yet written into its
+        #: rows: (slots, storage column slice, (T, w) bool plane)
+        self._pending: dict[int, tuple[np.ndarray, slice, np.ndarray]] = {}
         #: memoized resolve scalars and NOT success planes: pure functions
         #: of chip identity and the op context
         self._param_cache: dict = {}
@@ -540,12 +554,20 @@ class BankSim:
         for sub, rmap in self._rowmap.items():
             rmap.fill(-1)
             self._nrows[sub] = 0
+        self._pending.clear()
 
     def _cells(self, sub: int) -> np.ndarray:
-        """(T, slots, row_bits) backing buffer (slot order = first touch)."""
+        """(T, slots, row_bits) backing buffer (slot order = first touch),
+        with any pending APA result written into its rows first."""
         if sub not in self._subarrays:
             self._map_rows(sub, [0])    # force allocation
-        return self._subarrays[sub]
+        arr = self._subarrays[sub]
+        pending = self._pending.pop(sub, None)
+        if pending is not None:
+            slots, cols, plane = pending
+            arr[:, slots, cols] = plane[:, None, :]
+            tracing.count("sim.writeback_rows", len(slots))
+        return arr
 
     def _arr(self, sub: int) -> np.ndarray:
         """Cell voltages in *physical* row order: (rows, row_bits) in scalar
@@ -556,8 +578,9 @@ class BankSim:
         rmap = self._rowmap.get(sub)
         if rmap is not None:
             live = np.nonzero(rmap >= 0)[0]
-            out[:, live] = self._subarrays[sub][:, rmap[live]][
-                ..., self._invperm]
+            if live.size:
+                out[:, live] = self._cells(sub)[:, rmap[live]][
+                    ..., self._invperm]
         return out if self.batched else out[0]
 
     def _out(self, rows: np.ndarray) -> np.ndarray:
@@ -1002,17 +1025,14 @@ class BankSim:
                                             reg_l[b]), out=ok[sl])
             else:
                 ok = np.ones(src_bit.shape, dtype=bool)
-            dst_bit = np.where(ok, ~src_bit, src_bit).astype(np.float32)
-            src_f = src_bit.astype(np.float32)
-            arr_l[:, rows_l, l_cols] = dst_bit[:, None, :]
-            arr_f[:, rows_f, f_cols] = src_f[:, None, :]
+            out_l, out_f = np.where(ok, ~src_bit, src_bit), src_bit
         else:
             # ---- Boolean-op protocol: comparator across the stripe ----
             n_f, n_l = fact.n_rf, fact.n_rl
             u_f = A.u_n(n_f, self.params)
             u_l = A.u_n(n_l, self.params)
-            v_f = u_f * (np.sum(arr_f[:, rows_f, f_cols], axis=1)
-                         - 0.5 * n_f)
+            ref_cells = arr_f[:, rows_f, f_cols]
+            v_f = u_f * (np.sum(ref_cells, axis=1) - 0.5 * n_f)
             # noise context: the reference level sets the common mode
             # (V_REF > VDD/2 -> AND-family, < VDD/2 -> OR-family); every
             # bank runs the same op with same-sign references
@@ -1025,7 +1045,7 @@ class BankSim:
             if self.error_model == "analog" \
                     and self._resolve_backend() == "pallas":
                 out = self._resolve_pallas(
-                    arr_l[:, rows_l, l_cols], arr_f[:, rows_f, f_cols],
+                    arr_l[:, rows_l, l_cols], ref_cells,
                     u_l, u_f, stripe, op_ctx, n_l, regions=(reg_l, reg_f),
                     random_pattern=random_pattern, rng=rng)
             else:
@@ -1036,9 +1056,13 @@ class BankSim:
                 out = self._resolve(margin, stripe, op_ctx, n_l,
                                     regions=(reg_l, reg_f),
                                     random_pattern=random_pattern, rng=rng)
-            outf = out.astype(np.float32)
-            arr_l[:, rows_l, l_cols] = outf[:, None, :]
-            arr_f[:, rows_f, f_cols] = (1.0 - outf)[:, None, :]
+            out_l = np.asarray(out, dtype=bool)
+            out_f = ~out_l
+        # every activated row of a side now holds that side's plane: keep
+        # the planes until a row of them is read or reused (``_cells``)
+        tracing.count("sim.apa_rows", fact.n_rf + fact.n_rl)
+        self._pending[l_sub] = (rows_l, l_cols, out_l)
+        self._pending[f_sub] = (rows_f, f_cols, out_f)
         # non-shared columns: same-subarray restore (MAJ against VDD/2)
         other_f, other_l = self._other_slice(f_cols), self._other_slice(l_cols)
         if self.track_unshared:
@@ -1102,7 +1126,12 @@ class BankSim:
         full RD: the host pulls the row over the DDR bus to get the word."""
         i = self._row(sub, row)
         self._log_rd(sub)
-        return self._out((self._cells(sub)[:, i, sl] > 0.5).astype(np.uint8))
+        pending = self._pending.get(sub)
+        if pending is not None and pending[1] == sl and i in pending[0]:
+            bits = pending[2]               # the row holds the plane
+        else:                               # no pending plane covers the word
+            bits = self._subarrays[sub][:, i, sl] > 0.5
+        return self._out(bits.astype(np.uint8))
 
     def snapshot_rows(self, sub: int, rows) -> np.ndarray:
         """(n_rows, row_bits) digital snapshot; (T, n_rows, row_bits) when
